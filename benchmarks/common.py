@@ -61,6 +61,21 @@ def promote_baseline(name: str) -> str | None:
     return dst
 
 
+def require_devices(bench: str, n: int):
+    """Fail, naming the platform and device count, when fewer than ``n``
+    devices are visible: a benchmark never reports analytic numbers or
+    skips in place of a measurement."""
+    import jax
+    devs = jax.devices()
+    if len(devs) < n:
+        raise SystemExit(
+            f"{bench}: needs {n} devices, found {len(devs)} "
+            f"{devs[0].platform} device(s) ({devs[0].device_kind}); on the "
+            f"CPU run under XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n}")
+    return devs
+
+
 class timer:
     def __enter__(self):
         self.t0 = time.time()

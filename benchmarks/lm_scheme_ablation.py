@@ -8,15 +8,12 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import write_csv
+from benchmarks.common import require_devices, write_csv
 
 
 def run(steps: int = 16, local_steps: int = 2) -> list[dict]:
     import jax
-    if len(jax.devices()) < 8:
-        print("lm_scheme_ablation: needs XLA_FLAGS="
-              "--xla_force_host_platform_device_count=8; skipping")
-        return []
+    require_devices("lm_scheme_ablation", 8)
     import jax.numpy as jnp
     from repro.configs import get_reduced_config
     from repro.core.penalty import PenaltyConfig, SCHEMES

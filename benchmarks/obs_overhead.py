@@ -34,7 +34,7 @@ import time
 
 import numpy as np
 
-from benchmarks.common import write_json
+from benchmarks.common import require_devices, write_json
 
 RING_CAP = 64
 DRAIN_EVERY = 8
@@ -42,12 +42,9 @@ ROUNDS = 96     # quartile floor needs ~24 clean samples per variant; at 32
                 # rounds one loaded stretch still swung the ratio 0-4%
 
 
-def run(rounds: int = ROUNDS) -> dict | None:
+def run(rounds: int = ROUNDS) -> dict:
     import jax
-    if len(jax.devices()) < 8:
-        print("obs_overhead: needs 8 devices (run under "
-              "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
-        return None
+    require_devices("obs_overhead", 8)
     from repro.configs import get_reduced_config
     from repro.core.penalty import PenaltyConfig
     from repro.data import DataConfig, SyntheticTokens
@@ -164,7 +161,9 @@ def run(rounds: int = ROUNDS) -> dict | None:
 
     j = tr_on.num_nodes
     bench = {
-        "mesh": "2x2x2 (8 fake CPU devices)", "arch": "qwen3-4b (reduced)",
+        "mesh": f"2x2x2 ({len(jax.devices())} {jax.devices()[0].platform} "
+                f"devices, {jax.devices()[0].device_kind})",
+        "arch": "qwen3-4b (reduced)",
         "rounds": {
             "obs_off": {"round_ms": round(low_off * 1e3, 2)},
             "obs_scalar": {"round_ms": round(low_scalar * 1e3, 2)},

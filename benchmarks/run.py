@@ -3,6 +3,13 @@
 Prints a ``name,value,derived`` CSV summary at the end. Full sweeps:
 ``python -m benchmarks.run --full``.
 
+Process layout: this parent never imports JAX. Each cell runs in a child
+process of its own (``python -m benchmarks.run --cell NAME``), one after
+another, so a child always owns the devices outright (a parent holding a
+TPU would starve it), x64 turned on by the paper benches never leaks into
+the trainer cells, and a crash costs one cell, not the summary. A child
+prints its summary records as one ``RECORDS <json>`` line.
+
 Output layout (single-writer rule, see ``benchmarks/common.py``): every
 benchmark module writes only under ``benchmarks/results/``; THIS driver is
 the sole writer of the committed repo-root ``BENCH_*.json`` baselines — it
@@ -12,41 +19,48 @@ grid (``--full``), so smoke/CI runs can never clobber a baseline.
 from __future__ import annotations
 
 import argparse
+import csv
+import json
+import os
+import subprocess
 import sys
 import time
 
+CELLS = ("fig2", "fig3", "hopkins", "roofline", "consensus", "lm_ablation",
+         "topology", "async", "obs")
+# root baseline each cell's results artifact is promoted to (--full only)
+BASELINES = {"consensus": "BENCH_consensus.json",
+             "topology": "BENCH_topology.json",
+             "async": "BENCH_async.json", "obs": "BENCH_obs.json"}
+RESULTS = os.path.join(os.path.dirname(__file__), "results")
 
-def main(argv=None) -> None:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--full", action="store_true",
-                    help="paper-scale sweeps (20 seeds etc.)")
-    ap.add_argument("--only", default="all",
-                    choices=["all", "fig2", "fig3", "hopkins", "roofline",
-                             "consensus", "lm_ablation", "topology",
-                             "async", "obs"])
-    args = ap.parse_args(argv)
-    seeds = 20 if args.full else 3
 
-    summary = []
+def _read_csv(name):
+    with open(os.path.join(RESULTS, name)) as f:
+        return list(csv.DictReader(f))
 
-    def record(name, value, derived=""):
-        summary.append((name, value, derived))
 
-    def promote(name):
-        # single-writer rule: only this driver touches root baselines,
-        # and only when the full grid ran
-        if args.full:
-            from benchmarks.common import promote_baseline
-            path = promote_baseline(name)
-            if path:
-                record(f"promoted_{name}", path)
+def _read_json(name):
+    with open(os.path.join(RESULTS, name)) as f:
+        return json.load(f)
 
-    if args.only in ("all", "fig2"):
+
+def run_cell(name: str, full: bool) -> list[tuple]:
+    """Run one cell in THIS process; return its (name, value, derived)
+    summary records. Only ever called in a child."""
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    seeds = 20 if full else 3
+    out = []
+
+    def record(key, value, derived=""):
+        out.append((key, value, derived))
+
+    t0 = time.time()
+    if name == "fig2":
         from benchmarks import fig2_synthetic
-        t0 = time.time()
-        rows = fig2_synthetic.run(seeds=seeds if args.full else 2,
-                                  sizes=(12, 16, 20) if args.full
-                                  else (12, 20))
+        rows = fig2_synthetic.run(seeds=seeds if full else 2,
+                                  sizes=(12, 16, 20) if full else (12, 20))
         by = {(r["nodes"], r["topology"], r["scheme"]): r for r in rows}
         for j in sorted({r["nodes"] for r in rows}):
             base = by.get((j, "complete", "fixed"))
@@ -57,11 +71,9 @@ def main(argv=None) -> None:
                 record(f"fig2_J{j}_complete_vp_speedup_pct", round(sp, 1),
                        f"baseline={base['iters_median']:.0f}it")
         record("fig2_wall_s", round(time.time() - t0, 1))
-
-    if args.only in ("all", "fig3"):
+    elif name == "fig3":
         from benchmarks import fig3_sfm
-        t0 = time.time()
-        rows = fig3_sfm.run(seeds=seeds if args.full else 2)
+        rows = fig3_sfm.run(seeds=seeds if full else 2)
         by = {(r["topology"], r["t_max"], r["scheme"]): r for r in rows}
         b5 = by.get(("complete", 5, "fixed"))
         n5 = by.get(("complete", 5, "nap"))
@@ -71,20 +83,17 @@ def main(argv=None) -> None:
             record("fig3_tmax5_nap_speedup_pct", round(sp, 1),
                    "NAP accelerates where t_max-bound methods cannot")
         record("fig3_wall_s", round(time.time() - t0, 1))
-
-    if args.only in ("all", "hopkins"):
+    elif name == "hopkins":
         from benchmarks import tab_hopkins
-        t0 = time.time()
-        rows = tab_hopkins.run(num_objects=20 if args.full else 6,
-                               seeds=3 if args.full else 2)
+        rows = tab_hopkins.run(num_objects=20 if full else 6,
+                               seeds=3 if full else 2)
         for r in rows:
             if r["topology"] == "complete" and r["scheme"] in ("vp", "vp_ap"):
                 record(f"hopkins_complete_{r['scheme']}_speedup_pct",
                        r["speedup_vs_fixed_pct"],
                        "paper: vp=40.2 vp_ap=37.3")
         record("hopkins_wall_s", round(time.time() - t0, 1))
-
-    if args.only in ("all", "roofline"):
+    elif name == "roofline":
         from benchmarks import roofline
         rows = roofline.run()
         ok = [r for r in rows if r["status"] == "OK"]
@@ -93,41 +102,17 @@ def main(argv=None) -> None:
             record("roofline_cells_ok", len(ok), f"of {len(rows)}")
             record("roofline_frac_median",
                    round(sorted(fracs)[len(fracs) // 2], 4))
-
-    if args.only in ("all", "consensus"):
-        # own subprocess: the ppca benches enable x64 globally, which the
-        # trainer jit must not inherit (and a crash must not eat the summary)
-        import os
-        import subprocess
-        env = dict(os.environ)
-        env.setdefault("XLA_FLAGS",
-                       "--xla_force_host_platform_device_count=8")
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.consensus_overhead"],
-            capture_output=True, text=True, env=env, timeout=1800)
-        print(proc.stdout, end="")
-        if proc.returncode == 0:
-            import csv
-            path = os.path.join(os.path.dirname(__file__), "results",
-                                "consensus_overhead.csv")
-            if os.path.exists(path):
-                with open(path) as f:
-                    for r in csv.DictReader(f):
-                        if r["mode"] == "consensus_H16":
-                            record("consensus_H16_wire_vs_allreduce",
-                                   r["vs_allreduce"],
-                                   "cross-pod bytes ratio")
-            promote("BENCH_consensus.json")
-        else:
-            record("consensus_bench", "FAILED",
-                   proc.stderr.strip().splitlines()[-1][:80]
-                   if proc.stderr.strip() else "no stderr")
-
-    if args.only in ("all", "topology"):
+    elif name == "consensus":
+        from benchmarks import consensus_overhead
+        consensus_overhead.run()
+        for r in _read_csv("consensus_overhead.csv"):
+            if r["mode"] == "consensus_H16":
+                record("consensus_H16_wire_vs_allreduce", r["vs_allreduce"],
+                       "cross-pod bytes ratio")
+    elif name == "topology":
         from benchmarks import topology_dynamics
-        t0 = time.time()
-        rows = topology_dynamics.run(smoke=not args.full,
-                                     seeds=seeds if args.full else 1)
+        rows = topology_dynamics.run(smoke=not full,
+                                     seeds=seeds if full else 1)
         by = {(r["topology"], r["scheduler"]): r for r in rows}
         for topo in sorted({r["topology"] for r in rows}):
             b = by.get((topo, "budget"))
@@ -137,94 +122,93 @@ def main(argv=None) -> None:
                        f"iters={b['iters_median']:.0f} (vs static "
                        f"{by[(topo, 'static')]['iters_median']:.0f})")
         record("topology_wall_s", round(time.time() - t0, 1))
-        promote("BENCH_topology.json")
+    elif name == "async":
+        from benchmarks import async_staleness
+        async_staleness.main([] if full else ["--smoke"])
+        bench = _read_json("BENCH_async.json")
+        for r in bench["rows"]:
+            record(f"async_speedup_wire{r['wire_frac']}", r["speedup"],
+                   f"sync={r['rounds_sync']}r async={r['ticks_async']}t")
+        record("async_objective_drift", bench["objective_drift"],
+               "|f_async - f_sync| / f_sync")
+    elif name == "obs":
+        from benchmarks import obs_overhead
+        obs_overhead.run()
+        bench = _read_json("BENCH_obs.json")
+        record("obs_overhead_pct",
+               round(100 * bench["obs_overhead_ratio"], 2),
+               f"on={bench['rounds']['obs_on']['round_ms']}ms "
+               f"off={bench['rounds']['obs_off']['round_ms']}ms")
+    elif name == "lm_ablation":
+        from benchmarks import lm_scheme_ablation
+        lm_scheme_ablation.run()
+        rows = _read_csv("lm_scheme_ablation.csv")
+        best = min(rows, key=lambda r: float(r["final_loss"]))
+        record("lm_ablation_best_scheme", best["scheme"],
+               f"loss={best['final_loss']}")
+    else:
+        raise ValueError(f"unknown cell {name!r}")
+    return out
 
-    if args.only in ("all", "async"):
-        # own subprocess: needs the 8-device env like the consensus cell
-        import os
-        import subprocess
-        env = dict(os.environ)
-        env.setdefault("XLA_FLAGS",
-                       "--xla_force_host_platform_device_count=8")
-        cmd = [sys.executable, "-m", "benchmarks.async_staleness"]
-        if not args.full:
-            cmd.append("--smoke")
-        proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                              timeout=1800)
-        print(proc.stdout, end="")
-        if proc.returncode == 0:
-            import json
-            path = os.path.join(os.path.dirname(__file__), "results",
-                                "BENCH_async.json")
-            if os.path.exists(path):
-                with open(path) as f:
-                    bench = json.load(f)
-                for r in bench["rows"]:
-                    record(f"async_speedup_wire{r['wire_frac']}",
-                           r["speedup"],
-                           f"sync={r['rounds_sync']}r "
-                           f"async={r['ticks_async']}t")
-                record("async_objective_drift", bench["objective_drift"],
-                       "|f_async - f_sync| / f_sync")
-            promote("BENCH_async.json")
+
+def spawn_cell(name: str, full: bool) -> tuple[list[tuple], str]:
+    """Run one cell in a child process; (records, error or '')."""
+    env = dict(os.environ)
+    # the trainer cells need an 8-device mesh; on the CPU that is 8 fake
+    # host devices (the flag only touches the CPU platform)
+    env.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    cmd = [sys.executable, "-m", "benchmarks.run", "--cell", name] \
+        + (["--full"] if full else [])
+    proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
+                          timeout=3600)
+    records = []
+    for line in proc.stdout.splitlines():
+        if line.startswith("RECORDS "):
+            records = [tuple(r) for r in json.loads(line[len("RECORDS "):])]
         else:
-            record("async_bench", "FAILED",
-                   proc.stderr.strip().splitlines()[-1][:80]
-                   if proc.stderr.strip() else "no stderr")
+            print(line)
+    if proc.returncode != 0:
+        err = proc.stderr.strip().splitlines()
+        return records, (err[-1][:80] if err else f"rc {proc.returncode}")
+    return records, ""
 
-    if args.only in ("all", "obs"):
-        # own subprocess: needs the 8-device env like the consensus cell
-        import os
-        import subprocess
-        env = dict(os.environ)
-        env.setdefault("XLA_FLAGS",
-                       "--xla_force_host_platform_device_count=8")
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.obs_overhead"],
-            capture_output=True, text=True, env=env, timeout=1800)
-        print(proc.stdout, end="")
-        if proc.returncode == 0:
-            import json
-            path = os.path.join(os.path.dirname(__file__), "results",
-                                "BENCH_obs.json")
-            if os.path.exists(path):
-                with open(path) as f:
-                    bench = json.load(f)
-                record("obs_overhead_pct",
-                       round(100 * bench["obs_overhead_ratio"], 2),
-                       f"on={bench['rounds']['obs_on']['round_ms']}ms "
-                       f"off={bench['rounds']['obs_off']['round_ms']}ms")
-            promote("BENCH_obs.json")
-        else:
-            record("obs_bench", "FAILED",
-                   proc.stderr.strip().splitlines()[-1][:80]
-                   if proc.stderr.strip() else "no stderr")
 
-    if args.only in ("all", "lm_ablation"):
-        import os
-        import subprocess
-        env = dict(os.environ)
-        env.setdefault("XLA_FLAGS",
-                       "--xla_force_host_platform_device_count=8")
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.lm_scheme_ablation"],
-            capture_output=True, text=True, env=env, timeout=1800)
-        print(proc.stdout, end="")
-        if proc.returncode == 0:
-            import csv
-            path = os.path.join(os.path.dirname(__file__), "results",
-                                "lm_scheme_ablation.csv")
-            if os.path.exists(path):
-                with open(path) as f:
-                    rows = list(csv.DictReader(f))
-                best = min(rows, key=lambda r: float(r["final_loss"]))
-                record("lm_ablation_best_scheme", best["scheme"],
-                       f"loss={best['final_loss']}")
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--full", action="store_true",
+                    help="paper-scale sweeps (20 seeds etc.)")
+    ap.add_argument("--only", default="all", choices=("all",) + CELLS)
+    ap.add_argument("--cell", choices=CELLS,
+                    help="run one cell in this process and print its "
+                         "RECORDS line (what each child of the runner runs)")
+    args = ap.parse_args(argv)
+    if args.cell:
+        print("RECORDS " + json.dumps(run_cell(args.cell, args.full)),
+              flush=True)
+        return 0
+
+    summary, failed = [], False
+    for name in CELLS:
+        if args.only not in ("all", name):
+            continue
+        records, err = spawn_cell(name, args.full)
+        summary += records
+        if err:
+            failed = True
+            summary.append((f"{name}_bench", "FAILED", err))
+        elif args.full and name in BASELINES:
+            # single-writer rule: only this driver touches root baselines,
+            # and only when the full grid ran
+            from benchmarks.common import promote_baseline
+            path = promote_baseline(BASELINES[name])
+            if path:
+                summary.append((f"promoted_{BASELINES[name]}", path, ""))
 
     print("\nname,value,derived")
     for name, value, derived in summary:
         print(f"{name},{value},{derived}")
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -67,21 +67,6 @@ def current_mesh() -> Mesh | None:
     return _MESH.get()
 
 
-def abstract_mesh():
-    """``jax.sharding.get_abstract_mesh()`` where it exists (newer jax);
-    None on 0.4.x, where there is no ambient abstract-mesh context."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    return getter() if getter is not None else None
-
-
-def axis_size(name: str) -> int:
-    """``jax.lax.axis_size`` across jax versions (0.4.x: psum of the literal
-    1 over the axis, which constant-folds to a static int)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 def inpod_axes(mesh: Mesh | None) -> tuple[tuple[str, ...], int]:
     """Non-'pod' mesh axes and their total device count.
 
@@ -99,27 +84,17 @@ def inpod_axes(mesh: Mesh | None) -> tuple[tuple[str, ...], int]:
     return axes, size
 
 
-def shard_map_compat(fn, mesh, *, in_specs, out_specs, manual_axes=None):
-    """``shard_map`` across jax versions.
+def shard_map(fn, mesh, *, in_specs, out_specs, manual_axes=None):
+    """``jax.shard_map`` manual over ``manual_axes`` (all axes when None).
 
-    ``manual_axes`` selects the mesh axes the region is manual over (all
-    axes when None). Newer jax spells this ``jax.shard_map(...,
-    axis_names=...)``; 0.4.x spells the complement
-    ``jax.experimental.shard_map.shard_map(..., auto=...)``. Replication
-    checking is disabled in both (regions here replicate over unmentioned
-    in-pod axes on purpose).
+    Replication checking is off: regions here replicate over unmentioned
+    in-pod axes on purpose.
     """
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-        if manual_axes is not None:
-            kw["axis_names"] = frozenset(manual_axes)
-        return jax.shard_map(fn, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - frozenset(
-        manual_axes if manual_axes is not None else mesh.axis_names)
-    return _shard_map(fn, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False, auto=auto)
+    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+              check_vma=False)
+    if manual_axes is not None:
+        kw["axis_names"] = frozenset(manual_axes)
+    return jax.shard_map(fn, **kw)
 
 
 def logical_to_spec(axes: Sequence[str | None]) -> P:

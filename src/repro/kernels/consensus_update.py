@@ -95,7 +95,7 @@ def _kernel(scalars_ref, theta_ref, lam_ref, nbr_ref, bar_ref, barp_ref,
                    static_argnames=("block_size", "interpret"))
 def consensus_update(theta, lam, nbr_avg, theta_bar, theta_bar_prev, *,
                      eta_sum, eta_node, step_size,
-                     block_size: int = 65536, interpret: bool = True):
+                     block_size: int = 65536, interpret: bool):
     """All tensor args are flat [N] vectors; N need NOT be a block multiple.
 
     Non-multiple N is zero-padded internally: zero inputs are a fixed point
@@ -138,38 +138,47 @@ def consensus_update(theta, lam, nbr_avg, theta_bar, theta_bar_prev, *,
     return theta_new[:n], lam_new[:n], rsq.sum(), ssq.sum()
 
 
+def _write_partials(part_out, rsq, ssq):
+    """Both residual partials of one block as one lane-dense (1, 128) row:
+    lane 0 holds r_sq, lane 1 holds s_sq (Mosaic refuses (1, 1) blocks)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    part_out[0, 0] = jnp.where(lane == 0, rsq,
+                               jnp.where(lane == 1, ssq, 0.0))
+
+
 def _round_kernel(deg, per_block, block_leaf_ref, node_ref, esym_ref,
                   scale_ref, theta_ref, lam_ref, barp_ref, wires_ref,
-                  theta_out, lam_out, bar_out, rsq_out, ssq_out):
+                  theta_out, lam_out, bar_out, part_out):
+    i = pl.program_id(0)
     b = pl.program_id(1)
     # per-leaf scales resolve through the block->leaf table; per-block
     # scales (the fp8 codecs) index by the block id directly
     li = b if per_block else block_leaf_ref[b]
-    alpha = node_ref[0, 0]
-    eta_sum = node_ref[1, 0]
-    eta_node = node_ref[2, 0]
+    alpha = node_ref[0, i]
+    eta_sum = node_ref[1, i]
+    eta_node = node_ref[2, i]
 
-    theta = theta_ref[0, :].astype(jnp.float32)
-    lam = lam_ref[0, :].astype(jnp.float32)
-    barp = barp_ref[0, :].astype(jnp.float32)
+    theta = theta_ref[...].astype(jnp.float32)
+    lam = lam_ref[...].astype(jnp.float32)
+    barp = barp_ref[...].astype(jnp.float32)
 
     nbr_w = jnp.zeros_like(theta)
     nbr_p = jnp.zeros_like(theta)
     for d in range(deg):                      # static unroll over offsets
-        x = wires_ref[d, 0, :].astype(jnp.float32) * scale_ref[d, 0, li]
-        nbr_w = nbr_w + esym_ref[d, 0] * x
+        x = wires_ref[d].astype(jnp.float32) * scale_ref[d, i, li]
+        nbr_w = nbr_w + esym_ref[d, i] * x
         nbr_p = nbr_p + x
     bar = nbr_p * (1.0 / deg)
     nbr = nbr_w / jnp.maximum(eta_sum, 1e-12)
 
     theta_new = theta - alpha * (2.0 * lam + eta_sum * (theta - nbr))
     lam_new = lam + 0.5 * eta_sum * (theta_new - nbr)
-    theta_out[0, :] = theta_new.astype(theta_out.dtype)
-    lam_out[0, :] = lam_new.astype(lam_out.dtype)
-    bar_out[0, :] = bar.astype(bar_out.dtype)
-    rsq_out[0, 0] = jnp.sum((theta_new - bar) ** 2)
+    theta_out[...] = theta_new.astype(theta_out.dtype)
+    lam_out[...] = lam_new.astype(lam_out.dtype)
+    bar_out[...] = bar.astype(bar_out.dtype)
     dbar = bar - barp
-    ssq_out[0, 0] = (eta_node * eta_node) * jnp.sum(dbar * dbar)
+    _write_partials(part_out, jnp.sum((theta_new - bar) ** 2),
+                    (eta_node * eta_node) * jnp.sum(dbar * dbar))
 
 
 def _row_kernel(deg, block_size, per_block, block_leaf_ref, node_ref,
@@ -223,32 +232,33 @@ def _round_kernel_masked(deg, has_kick, per_block, block_leaf_ref, node_ref,
     """Edge-gated variant of ``_round_kernel`` (see module docstring)."""
     if has_kick:
         (kick_ref, scale_ref, theta_ref, lam_ref, barp_ref, wires_ref,
-         theta_out, lam_out, bar_out, rsq_out, ssq_out) = refs
+         theta_out, lam_out, bar_out, part_out) = refs
     else:
         (scale_ref, theta_ref, lam_ref, barp_ref, wires_ref,
-         theta_out, lam_out, bar_out, rsq_out, ssq_out) = refs
+         theta_out, lam_out, bar_out, part_out) = refs
+    i = pl.program_id(0)
     b = pl.program_id(1)
     li = b if per_block else block_leaf_ref[b]
-    alpha = node_ref[0, 0]
-    eta_sum = node_ref[1, 0]
-    eta_node = node_ref[2, 0]
-    inv_deg = node_ref[3, 0]
+    alpha = node_ref[0, i]
+    eta_sum = node_ref[1, i]
+    eta_node = node_ref[2, i]
+    inv_deg = node_ref[3, i]
 
-    theta = theta_ref[0, :].astype(jnp.float32)
-    lam = lam_ref[0, :].astype(jnp.float32)
-    barp = barp_ref[0, :].astype(jnp.float32)
+    theta = theta_ref[...].astype(jnp.float32)
+    lam = lam_ref[...].astype(jnp.float32)
+    barp = barp_ref[...].astype(jnp.float32)
 
     nbr_w = jnp.zeros_like(theta)
     nbr_p = jnp.zeros_like(theta)
     kick_x = jnp.zeros_like(theta)
     ksum = jnp.float32(0.0)
     for d in range(deg):                      # static unroll over offsets
-        x = wires_ref[d, 0, :].astype(jnp.float32) * scale_ref[d, 0, li]
-        nbr_w = nbr_w + esym_ref[d, 0] * x
-        nbr_p = nbr_p + barw_ref[d, 0] * x
+        x = wires_ref[d].astype(jnp.float32) * scale_ref[d, i, li]
+        nbr_w = nbr_w + esym_ref[d, i] * x
+        nbr_p = nbr_p + barw_ref[d, i] * x
         if has_kick:
-            kick_x = kick_x + kick_ref[d, 0] * x
-            ksum = ksum + kick_ref[d, 0]
+            kick_x = kick_x + kick_ref[d, i] * x
+            ksum = ksum + kick_ref[d, i]
     bar = nbr_p * inv_deg
     nbr = nbr_w / jnp.maximum(eta_sum, 1e-12)
 
@@ -258,12 +268,12 @@ def _round_kernel_masked(deg, has_kick, per_block, block_leaf_ref, node_ref,
         # zero-kick: absorb newly-gated edges' final consensus force
         # 0.5 sum_d kick_d (theta - x_d) into the dual (round-start iterate)
         lam_new = lam_new + 0.5 * (ksum * theta - kick_x)
-    theta_out[0, :] = theta_new.astype(theta_out.dtype)
-    lam_out[0, :] = lam_new.astype(lam_out.dtype)
-    bar_out[0, :] = bar.astype(bar_out.dtype)
-    rsq_out[0, 0] = jnp.sum((theta_new - bar) ** 2)
+    theta_out[...] = theta_new.astype(theta_out.dtype)
+    lam_out[...] = lam_new.astype(lam_out.dtype)
+    bar_out[...] = bar.astype(bar_out.dtype)
     dbar = bar - barp
-    ssq_out[0, 0] = (eta_node * eta_node) * jnp.sum(dbar * dbar)
+    _write_partials(part_out, jnp.sum((theta_new - bar) ** 2),
+                    (eta_node * eta_node) * jnp.sum(dbar * dbar))
 
 
 def _row_kernel_masked(deg, block_size, has_kick, per_block, block_leaf_ref,
@@ -380,7 +390,7 @@ def _row_round(theta, lam, bar_prev, wires, scales, e_sym, node_scalars,
 def consensus_round(theta, lam, bar_prev, wires, scales, e_sym,
                     alpha, eta_sum, eta_node, *,
                     block_leaf: tuple[int, ...] | None, block_size: int,
-                    interpret: bool = True,
+                    interpret: bool,
                     whole_rows: bool | None = None,
                     bar_w=None, inv_deg=None, kick_w=None,
                     block_leaf_arr=None, scales_per_block: bool = False):
@@ -429,8 +439,9 @@ def consensus_round(theta, lam, bar_prev, wires, scales, e_sym,
     in place.
 
     ``whole_rows`` (default: follow ``interpret``) switches to one grid
-    step per node row — the interpreter tiling; the VMEM-sized blocked grid
-    is for real TPU runs (and stays testable via ``whole_rows=False``).
+    step per node row — the interpreter tiling, which Mosaic does not
+    lower; the VMEM-sized blocked grid is what compiles for the TPU (and
+    stays testable in interpret mode via ``whole_rows=False``).
     """
     j, total = theta.shape
     deg = wires.shape[0]
@@ -455,38 +466,38 @@ def consensus_round(theta, lam, bar_prev, wires, scales, e_sym,
     if scales_per_block:
         assert scales.shape[-1] == nblocks, (scales.shape, nblocks)
 
-    if interpret if whole_rows is None else whole_rows:
+    if whole_rows is None:
+        whole_rows = interpret
+    assert interpret or not whole_rows, \
+        "the whole-row tiling is interpret-only; compiled runs use blocks"
+    if whole_rows:
         tn, ln, bar, rsq, ssq = _row_round(
             theta, lam, bar_prev, wires, scales, e_sym, node_scalars,
             block_leaf_arr, block_size=block_size, interpret=interpret,
             bar_w=bar_w, kick_w=kick_w, scales_per_block=scales_per_block)
         return tn, ln, bar, rsq[:, 0], ssq[:, 0]
 
+    # Mosaic tiling: the row blocks are (1, block_size), legal because one
+    # node row is the whole second-minor dim (the trainer's shard_map hands
+    # each device J = 1); the per-node scalars, edge weights and dequant
+    # scales are whole SMEM operands indexed by the grid position; the
+    # residual partials leave as lane-dense (1, 128) rows per block.
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vec = pl.BlockSpec((1, block_size), lambda i, b: (i, b))
     wire_spec = pl.BlockSpec((deg, 1, block_size), lambda i, b: (0, i, b))
-    part = pl.BlockSpec((1, 1), lambda i, b: (i, b))
+    part = pl.BlockSpec((1, 1, 1, 128), lambda i, b: (i, b, 0, 0))
 
-    nscal = node_scalars.shape[0]
-    in_specs = [
-        smem,                        # block -> leaf table
-        pl.BlockSpec((nscal, 1), lambda i, b: (0, i),
-                     memory_space=pltpu.SMEM),        # per-node scalars
-        pl.BlockSpec((deg, 1), lambda i, b: (0, i),
-                     memory_space=pltpu.SMEM),        # e_sym
-    ]
+    # block -> leaf table, per-node scalars, e_sym
+    in_specs = [smem, smem, smem]
     args = [block_leaf_arr, node_scalars, e_sym.astype(jnp.float32)]
     if masked:
-        in_specs.append(pl.BlockSpec((deg, 1), lambda i, b: (0, i),
-                                     memory_space=pltpu.SMEM))  # edge gates
+        in_specs.append(smem)                         # edge gates
         args.append(bar_w.astype(jnp.float32))
     if kick_w is not None:
-        in_specs.append(pl.BlockSpec((deg, 1), lambda i, b: (0, i),
-                                     memory_space=pltpu.SMEM))  # zero-kick
+        in_specs.append(smem)                         # zero-kick weights
         args.append(kick_w.astype(jnp.float32))
     in_specs += [
-        pl.BlockSpec((deg, 1, scales.shape[-1]), lambda i, b: (0, i, 0),
-                     memory_space=pltpu.SMEM),        # dequant scales
+        smem,                                         # dequant scales
         vec, vec, vec,               # theta, lam, bar_prev
         wire_spec,
     ]
@@ -497,20 +508,20 @@ def consensus_round(theta, lam, bar_prev, wires, scales, e_sym,
                                 kick_w is not None, scales_per_block)
               if masked
               else functools.partial(_round_kernel, deg, scales_per_block))
-    theta_new, lam_new, bar, rsq, ssq = pl.pallas_call(
+    theta_new, lam_new, bar, parts = pl.pallas_call(
         kernel,
         grid=(j, nblocks),
         in_specs=in_specs,
-        out_specs=[vec, vec, vec, part, part],
+        out_specs=[vec, vec, vec, part],
         out_shape=[
             jax.ShapeDtypeStruct((j, total), theta.dtype),
             jax.ShapeDtypeStruct((j, total), lam.dtype),
             jax.ShapeDtypeStruct((j, total), jnp.float32),
-            jax.ShapeDtypeStruct((j, nblocks), jnp.float32),
-            jax.ShapeDtypeStruct((j, nblocks), jnp.float32),
+            jax.ShapeDtypeStruct((j, nblocks, 1, 128), jnp.float32),
         ],
         # in-place: theta->theta_new, lam->lam_new, bar_prev->bar
         input_output_aliases={ab: 0, ab + 1: 1, ab + 2: 2},
         interpret=interpret,
     )(*args)
-    return theta_new, lam_new, bar, rsq.sum(axis=1), ssq.sum(axis=1)
+    return (theta_new, lam_new, bar, parts[:, :, 0, 0].sum(axis=1),
+            parts[:, :, 0, 1].sum(axis=1))

@@ -81,7 +81,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q: [B, H, S, hd]; k, v: [B, K, S, hd] with H = K * n_rep."""
     b, h, s, hd = q.shape
     kheads = k.shape[1]

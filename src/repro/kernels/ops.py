@@ -1,16 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` is auto-detected: compiled Mosaic on TPU backends, Pallas
-interpret mode (kernel body evaluated with plain HLO ops — jit/shard_map
-traceable) everywhere else. Override order:
-
-  1. ``repro.kernels.ops.INTERPRET = True/False`` (module attribute),
-  2. ``REPRO_PALLAS_INTERPRET=1/0`` in the environment,
-  3. ``jax.default_backend() != "tpu"``.
+The mode follows the backend and nothing else: compiled Mosaic on a TPU,
+Pallas interpret mode (kernel body evaluated with plain HLO ops — jit and
+shard_map traceable) on every other backend. There is no override, so a
+TPU run can never fall back to the interpreter.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -19,19 +14,9 @@ from repro.kernels import consensus_update as _cu
 from repro.kernels import flash_attention as _fa
 from repro.kernels import rwkv6_scan as _rw
 
-INTERPRET: bool | None = None    # None => auto (env var, then backend probe)
-
-_ENV_VAR = "REPRO_PALLAS_INTERPRET"
-_TRUTHY = ("1", "true", "yes", "on")
-
 
 def interpret_mode() -> bool:
-    """Resolve whether Pallas kernels should run in interpret mode."""
-    if INTERPRET is not None:
-        return bool(INTERPRET)
-    env = os.environ.get(_ENV_VAR, "").strip().lower()
-    if env:
-        return env in _TRUTHY
+    """True unless the default backend is a TPU (compiled Mosaic there)."""
     return jax.default_backend() != "tpu"
 
 

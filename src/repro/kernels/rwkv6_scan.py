@@ -79,7 +79,7 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sout_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6_scan(r, k, v, log_w, u, s0, *, chunk: int = 32,
-               interpret: bool = True):
+               interpret: bool):
     """r,k,v,log_w: [B, H, T, hd]; u: [H, hd]; s0: [B, H, hd, hd].
 
     Returns (y [B, H, T, hd], s_final [B, H, hd, hd]).

@@ -17,14 +17,30 @@ import jax
 # optimization_barriers — see docs/consensus_engine.md "Round pipeline").
 # Async collective conversion itself is default-on in this XLA vintage
 # (the old --xla_gpu_enable_async_collectives flag no longer exists), so
-# the tunables that matter are the scheduler + stream priority + pipelined
-# collectives. All three parse on every backend (the registry is global);
-# CPU simply ignores the gpu-prefixed knobs.
+# the tunables that matter are the scheduler + stream priority. Both parse
+# on every backend (the registry is global), but only the GPU compiler
+# reads them, so the launcher arms them only where a GPU plugin is
+# installed (``gpu_plugin_installed``). XLA aborts the process at backend
+# start on a flag it does not know: --xla_gpu_enable_pipelined_collectives
+# is gone from the installed XLA and must not come back here.
 ASYNC_COLLECTIVE_FLAGS = (
     "--xla_gpu_enable_latency_hiding_scheduler=true",
     "--xla_gpu_enable_highest_priority_async_stream=true",
-    "--xla_gpu_enable_pipelined_collectives=true",
 )
+
+
+def gpu_plugin_installed() -> bool:
+    """True when a CUDA or ROCm PJRT plugin for jax is installed.
+
+    Decided from installed distributions, so it can run before the first
+    jax backend touch (when XLA_FLAGS must already be final).
+    """
+    from importlib import metadata
+    for dist in metadata.distributions():
+        name = (dist.metadata["Name"] or "").lower().replace("_", "-")
+        if name.startswith(("jax-cuda", "jax-rocm")):
+            return True
+    return False
 
 
 def backend_initialized() -> bool:
@@ -79,17 +95,9 @@ def set_backend_flags(*, async_collectives: bool = True,
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist in newer jax;
-    on 0.4.x every axis is Auto by default, which is exactly what we want, so
-    the kwarg is simply omitted when the enum is missing.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (GSPMD-partitioned)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -97,6 +105,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return make_mesh(shape, axes)
+
+
+def make_local_mesh():
+    """Every local device as one consensus node: ("pod", "data", "model")
+    of shape (devices, 1, 1). One chip gives J = 1; a four-chip host gives
+    J = 4, one node per chip."""
+    return make_mesh((len(jax.devices()), 1, 1), ("pod", "data", "model"))
 
 
 def make_debug_mesh(*, multi_pod: bool = False):

@@ -1,17 +1,21 @@
 """Training launcher: consensus-ADMM distributed training end to end.
 
-CPU-scale demo / integration entry (reduced configs); identical code path on
-real TPU — only the mesh and config sizes change.
+The same code path runs the reduced configs on CPU fake devices and the
+published widths on a TPU; only the mesh and the config sizes change.
 
-Example:
+Examples:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --reduced \\
       --steps 40 --scheme nap --topology ring --local-steps 4 \\
       --ckpt-dir /tmp/ckpt
+  # published widths, depth and vocabulary cut, one node per local chip
+  PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --mesh local \\
+      --n-layers 4 --vocab 18944 --seq 2048 --batch-per-node 2
 Resume is automatic if the checkpoint dir has state.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import time
 
@@ -25,7 +29,8 @@ from repro.checkpoint import latest_steps, restore, save_async, wait_pending
 from repro.configs import get_config, get_reduced_config
 from repro.core.penalty import PenaltyConfig, SCHEMES
 from repro.data import DataConfig, SyntheticTokens
-from repro.launch.mesh import (make_debug_mesh, make_production_mesh,
+from repro.launch.mesh import (gpu_plugin_installed, make_debug_mesh,
+                               make_local_mesh, make_production_mesh,
                                set_backend_flags)
 from repro.models import build_model
 from repro.obs import ObsConfig, ObsWriter, host_span_factory
@@ -33,6 +38,7 @@ from repro.optim import ConsensusConfig, ConsensusTrainer
 from repro.optim.adamw import AdamWConfig
 from repro.runtime import (ElasticController, RetryPolicy, StragglerMonitor,
                            aged_out_nodes, with_retries)
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.topology import SCHEDULERS as TOPO_SCHEDULERS, TopologyConfig
 
 
@@ -44,9 +50,22 @@ def parse_args(argv=None):
     ap.add_argument("--steps", type=int, default=24)
     ap.add_argument("--batch-per-node", type=int, default=4)
     ap.add_argument("--seq", type=int, default=32)
-    ap.add_argument("--mesh", choices=["debug", "prod", "none"],
-                    default="debug")
-    ap.add_argument("--multi-pod", action="store_true", default=True)
+    ap.add_argument("--n-layers", type=int, default=0,
+                    help="cut the config's depth to N layers (widths stay "
+                         "as the config has them); 0 keeps its depth")
+    ap.add_argument("--vocab", type=int, default=0,
+                    help="cut the config's vocabulary to N rows; 0 keeps it")
+    ap.add_argument("--mesh", choices=["debug", "prod", "local", "none"],
+                    default="debug",
+                    help="debug = 8 CPU fake devices, prod = 256/512-chip "
+                         "v5e pods, local = every local device, one "
+                         "consensus node each: ('pod', 'data', 'model') "
+                         "of shape (devices, 1, 1)")
+    ap.add_argument("--multi-pod", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="debug/prod meshes: add the 'pod' axis that "
+                         "carries the consensus graph (--no-multi-pod "
+                         "trains one node)")
     ap.add_argument("--scheme", choices=SCHEMES, default="nap")
     ap.add_argument("--topology", default="ring")
     ap.add_argument("--topo-scheduler", choices=TOPO_SCHEDULERS,
@@ -85,10 +104,12 @@ def parse_args(argv=None):
                          "sequential loop, bit-identical at every depth; "
                          "docs/consensus_engine.md \"Round pipeline\")")
     ap.add_argument("--no-async-collectives", action="store_true",
-                    help="skip arming the XLA latency-hiding/async-stream "
-                         "flags (set_backend_flags) before jax init; the "
-                         "pipeline still reorders issue/consume but the "
-                         "scheduler won't hide the permutes")
+                    help="skip arming the XLA GPU latency-hiding/async-"
+                         "stream flags (set_backend_flags) before jax init; "
+                         "they are armed only where a GPU plugin is "
+                         "installed. The pipeline still reorders "
+                         "issue/consume but the scheduler won't hide the "
+                         "permutes")
     ap.add_argument("--local-steps", type=int, default=4)
     ap.add_argument("--eta0", type=float, default=0.1)
     ap.add_argument("--lr", type=float, default=1e-2)
@@ -137,26 +158,34 @@ def parse_args(argv=None):
     return args
 
 
-def main(argv=None):
-    args = parse_args(argv)
-    if not args.no_async_collectives:
-        # must land before the first jax device touch (build_model / mesh
-        # construction below) — a warn-no-op afterwards
-        set_backend_flags(async_collectives=True)
+def arch_config(args):
+    """The run's ArchConfig: the full or reduced config, with only its
+    depth and vocabulary cut by --n-layers / --vocab."""
     cfg = get_reduced_config(args.arch) if args.reduced \
         else get_config(args.arch)
+    cuts = {}
+    if args.n_layers:
+        cuts["n_layers"] = args.n_layers
+    if args.vocab:
+        cuts["vocab"] = args.vocab
+    return dataclasses.replace(cfg, **cuts), cfg
+
+
+def build_trainer(args):
+    """(cfg, trainer) exactly as ``main`` runs them — also what a caller
+    compiles ahead of the run to check the fit on the device."""
+    cfg, _ = arch_config(args)
     model = build_model(cfg)
     if args.mesh == "prod":
         mesh = make_production_mesh(multi_pod=args.multi_pod)
     elif args.mesh == "debug":
         mesh = make_debug_mesh(multi_pod=args.multi_pod)
+    elif args.mesh == "local":
+        mesh = make_local_mesh()
     else:
         mesh = None
 
-    drop_at, drop_victim = (-1, -1)
-    if args.drop_node:
-        drop_at, drop_victim = (int(x) for x in args.drop_node.split(":"))
-    churn = args.topo_churn or args.drop_stragglers or drop_at >= 0
+    churn = args.topo_churn or args.drop_stragglers or bool(args.drop_node)
     topo_sched = args.topo_scheduler
     if args.async_mode and topo_sched == "static" and args.max_staleness > 0:
         # the stale scheduler mirrors the executor's in-round gating into
@@ -166,7 +195,7 @@ def main(argv=None):
                         drain_every=args.obs_drain_every,
                         with_node_ring=not args.no_node_ring) \
         if args.obs_dir else None
-    trainer = ConsensusTrainer(
+    return cfg, ConsensusTrainer(
         model, mesh,
         adamw=AdamWConfig(lr=args.lr),
         consensus=ConsensusConfig(
@@ -181,6 +210,26 @@ def main(argv=None):
             async_exec=(AsyncConfig(max_staleness=args.max_staleness)
                         if args.async_mode else None),
             obs=obs_cfg))
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    if not args.no_async_collectives and gpu_plugin_installed():
+        # must land before the first jax device touch (build_model / mesh
+        # construction below) — a warn-no-op afterwards. The flags are
+        # GPU-compiler options; no other backend reads them.
+        set_backend_flags(async_collectives=True)
+    enable_compile_cache()
+    cfg, base = arch_config(args)
+    for name in ("n_layers", "vocab"):
+        if getattr(cfg, name) != getattr(base, name):
+            print(f"cut: {name} {getattr(base, name)} -> "
+                  f"{getattr(cfg, name)} ({cfg.arch_id}, widths unchanged)",
+                  flush=True)
+    cfg, trainer = build_trainer(args)
+    drop_at, drop_victim = (-1, -1)
+    if args.drop_node:
+        drop_at, drop_victim = (int(x) for x in args.drop_node.split(":"))
     state = trainer.init_state(jax.random.PRNGKey(args.seed))
     start_step = 0
     if args.ckpt_dir and latest_steps(args.ckpt_dir):
@@ -195,7 +244,17 @@ def main(argv=None):
 
     # local step stays undonated: with_retries may replay it with the same
     # state buffers; the consensus round is never retried, so donate there.
-    train = jax.jit(trainer.train_step)
+    # The local step never reads the flat consensus buffers, so they stay
+    # out of its jit: an undonated jit would copy them into its outputs,
+    # 8 B/param of HBM (plus the wire ledger) for nothing.
+    train_jit = jax.jit(trainer.train_step)
+
+    def train(s, b):
+        keep = {"lam": s.lam, "theta_bar_prev": s.theta_bar_prev,
+                "ledger": s.ledger}
+        new, m = train_jit(s._replace(lam=None, theta_bar_prev=None,
+                                      ledger=None), b)
+        return new._replace(**keep), m
     _, cons = trainer.jit_step_fns()
     executor = None
     if args.async_mode and trainer.num_nodes > 1:
@@ -209,7 +268,7 @@ def main(argv=None):
             offsets=tuple(trainer.offsets)))
     monitor = StragglerMonitor(trainer.num_nodes)
     elastic = ElasticController(trainer.graph, topology=trainer.topo_rt)
-    step_fn = with_retries(lambda s, b: train(s, b), RetryPolicy())
+    step_fn = with_retries(train, RetryPolicy())
 
     writer = None
     if args.obs_dir:
@@ -245,12 +304,9 @@ def main(argv=None):
         if trainer.should_sync(step):
             probe = make_batch(10**6 + step)
             if args.profile_rounds > 0 and rounds == 0 and not profiling:
-                try:
-                    jax.profiler.start_trace(
-                        os.path.join(args.obs_dir or ".", "profile"))
-                    profiling = True
-                except Exception as e:  # profiler backend unavailable
-                    print(f"profiler unavailable: {e}", flush=True)
+                jax.profiler.start_trace(
+                    os.path.join(args.obs_dir or ".", "profile"))
+                profiling = True
             with round_span("round/async" if executor is not None
                             else "round/sync"):
                 if executor is not None:

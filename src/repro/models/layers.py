@@ -54,8 +54,11 @@ def mlp_apply(p: dict, x: jax.Array) -> jax.Array:
 
 # ----------------------------------------------------------- embeddings -----
 def embed_defs(cfg: ArchConfig, dtype) -> dict:
+    # a tied table is also the output head: at unit scale its logits would
+    # have std sqrt(d_model), so it takes the published init std 0.02
+    init = "normal" if cfg.tie_embeddings else "embed"
     out = {"embed": ParamDef((cfg.vocab, cfg.d_model), ("vocab", "fsdp"),
-                             dtype, init="embed", scale=0.02)}
+                             dtype, init=init, scale=0.02)}
     if not cfg.tie_embeddings:
         out["lm_head"] = ParamDef((cfg.d_model, cfg.vocab),
                                   ("fsdp", "vocab"), dtype)

@@ -26,8 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.distributed.sharding import current_mesh
-from repro.distributed.sharding import axis_size as shd_axis_size
+from repro.distributed.sharding import current_mesh, shard_map
 from repro.models.params import ParamDef
 
 
@@ -118,7 +117,7 @@ def _moe_shard_a2a(cfg, ep_axis):
         b, s, d = x.shape
         x_flat = x.reshape(-1, d)
         t_loc = x_flat.shape[0]
-        ep = shd_axis_size(ep_axis)
+        ep = jax.lax.axis_size(ep_axis)
         e_local = e.num_experts // ep
         capacity = max(e.top_k, int(t_loc * e.top_k / ep
                                     * e.capacity_factor))
@@ -156,7 +155,7 @@ def _moe_shard_repl(cfg, ep_axis):
         b, s, d = x.shape
         x_flat = x.reshape(-1, d)
         t_loc = x_flat.shape[0]
-        ep = shd_axis_size(ep_axis)
+        ep = jax.lax.axis_size(ep_axis)
         e_local = e.num_experts // ep
         my = jax.lax.axis_index(ep_axis)
         top_i, top_w = _route(cfg, router_w, x_flat)
@@ -196,9 +195,8 @@ def moe_apply(cfg: ArchConfig, p: dict, x: jax.Array, *,
             or cfg.moe.num_experts % mesh.shape["model"] != 0:
         return moe_ref(cfg, p, x)
 
-    from repro.distributed.sharding import abstract_mesh
-    abstract = abstract_mesh()
-    if abstract is not None and abstract.shape_tuple:
+    abstract = jax.sharding.get_abstract_mesh()
+    if abstract.shape_tuple:
         manual_already = {name for name, ty in
                           zip(abstract.axis_names, abstract.axis_types)
                           if str(ty) == "Manual"}
@@ -227,8 +225,7 @@ def moe_apply(cfg: ArchConfig, p: dict, x: jax.Array, *,
     # (hlo_instruction.cc "Invalid binary instruction opcode copy");
     # axes not used in specs are simply replicated-manual.
     axis_names = set(run_mesh.axis_names) - manual_already
-    from repro.distributed.sharding import shard_map_compat
-    return shard_map_compat(
+    return shard_map(
         fn, run_mesh,
         in_specs=(P(None, None), w_spec, w_spec, w_spec, x_spec),
         out_specs=out_spec,

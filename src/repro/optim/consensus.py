@@ -194,6 +194,19 @@ class ConsensusTrainer:
             tree)
 
     def init_state(self, key: jax.Array) -> TrainState:
+        """Fresh state, built already laid out by ``state_shardings``.
+
+        With a pod axis the state is made inside one jit whose outputs are
+        sharded, so each node's replica is created on its own pod's
+        devices; made eagerly, all J replicas would first land on one
+        device, which at published widths does not fit.
+        """
+        if not self.has_pod:
+            return self._init_state(key)
+        return jax.jit(self._init_state,
+                       out_shardings=self.state_shardings())(key)
+
+    def _init_state(self, key: jax.Array) -> TrainState:
         with shd.use_mesh(self.mesh, self.inner_rules):
             params1 = self.model.init(key)
         params = self._node_stack(params1)
@@ -610,7 +623,7 @@ class ConsensusTrainer:
                        jax.lax.psum(ssq, inner))
             return out
 
-        fn = shd.shard_map_compat(
+        fn = shd.shard_map(
             local, self.mesh, in_specs=in_specs,
             out_specs=(flat_spec, flat_spec, flat_spec, pod, pod))
         with self._span("consensus/fused_round"):

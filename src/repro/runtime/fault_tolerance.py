@@ -52,15 +52,23 @@ class RetryPolicy:
 def with_retries(fn: Callable, policy: RetryPolicy,
                  *, on_retry: Callable[[int, Exception], None] | None = None,
                  sleep: Callable[[float], None] = time.sleep):
-    """Wrap a step function in bounded retry-with-backoff."""
+    """Wrap a step function in bounded retry-with-backoff.
+
+    Every retry is printed (``retry k/n: <error>``), never silent. An
+    out-of-memory error (XLA's ``RESOURCE_EXHAUSTED``) is not retried: the
+    same step on the same state would fail the same way.
+    """
     def wrapped(*args, **kwargs):
         delay = policy.backoff_s
         for attempt in range(policy.max_retries + 1):
             try:
                 return fn(*args, **kwargs)
             except policy.retryable as e:
-                if attempt == policy.max_retries:
+                if attempt == policy.max_retries \
+                        or "RESOURCE_EXHAUSTED" in str(e):
                     raise
+                print(f"retry {attempt + 1}/{policy.max_retries}: "
+                      f"{type(e).__name__}: {e}", flush=True)
                 if on_retry is not None:
                     on_retry(attempt, e)
                 sleep(delay)

@@ -116,7 +116,10 @@ for scheme in SCHEMES:
 
 # --- communication contract: permutes per offset, pallas calls per round --
 tr = make("nap", "int8", True)
-st = tr.init_state(jax.random.PRNGKey(2))
+# a host copy: uncommitted inputs leave GSPMD free to pick the round's own
+# shardings. (init_state's committed layout adds one small in-pod s32
+# reshard of the probe's embedding-gather index — not a wire permute.)
+st = jax.device_get(tr.init_state(jax.random.PRNGKey(2)))
 jaxpr = jax.make_jaxpr(tr.consensus_step)(st, probe)
 out["pallas_calls"] = str(jaxpr).count("pallas_call")
 compiled = jax.jit(tr.consensus_step).lower(st, probe).compile()

@@ -162,6 +162,36 @@ def test_with_retries_exhausts():
                      sleep=lambda _: None)()
 
 
+def test_with_retries_prints_every_retry(capsys):
+    calls = {"n": 0}
+
+    def flaky():
+        calls["n"] += 1
+        if calls["n"] < 3:
+            raise RuntimeError("transient")
+        return "ok"
+
+    with_retries(flaky, RetryPolicy(max_retries=3, backoff_s=0.0),
+                 sleep=lambda _: None)()
+    lines = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("retry ")]
+    assert lines == ["retry 1/3: RuntimeError: transient",
+                     "retry 2/3: RuntimeError: transient"]
+
+
+def test_with_retries_does_not_retry_out_of_memory():
+    calls = {"n": 0}
+
+    def oom():
+        calls["n"] += 1
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        with_retries(oom, RetryPolicy(max_retries=3, backoff_s=0.0),
+                     sleep=lambda _: None)()
+    assert calls["n"] == 1
+
+
 def test_straggler_monitor_flags_slow_node():
     mon = StragglerMonitor(4, threshold=2.0, patience=2)
     base = np.array([1.0, 1.0, 1.0, 1.0])

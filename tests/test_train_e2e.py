@@ -82,3 +82,63 @@ def test_straggler_to_elastic_pipeline():
     assert out["victims"] == [3]
     assert out["nodes"] == 3 and out["connected"]
     assert out["pen_shape"] == [3, 3]
+
+
+_LOCAL_MESH = r"""
+import contextlib, io, json, math, os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+from repro.launch import train
+argv = ["--arch", "qwen3-4b", "--reduced", "--mesh", "local",
+        "--n-layers", "1", "--vocab", "128", "--steps", "4",
+        "--local-steps", "2", "--seq", "16", "--batch-per-node", "2"]
+buf = io.StringIO()
+with contextlib.redirect_stdout(buf):
+    rc = train.main(argv)
+cfg, tr = train.build_trainer(train.parse_args(argv))
+state = tr.init_state(jax.random.PRNGKey(0))
+owners = {sh.index[0].start: sh.device.id
+          for sh in jax.tree_util.tree_leaves(state.params)[0]
+          .addressable_shards}
+print("RESULT " + json.dumps({
+    "rc": rc, "lines": buf.getvalue().splitlines(), "J": tr.num_nodes,
+    "layers": cfg.n_layers, "vocab": cfg.vocab, "d_model": cfg.d_model,
+    "owners": sorted(owners.items())}))
+"""
+
+
+def test_local_mesh_runs_ring_with_depth_and_vocab_cuts():
+    out = _run(_LOCAL_MESH, timeout=600)
+    assert out["rc"] == 0
+    lines = out["lines"]
+    assert "cut: n_layers 2 -> 1 (qwen3-4b, widths unchanged)" in lines
+    assert "cut: vocab 256 -> 128 (qwen3-4b, widths unchanged)" in lines
+    # one node per device: J = 4 on a (4, 1, 1) mesh, node i on device i
+    assert out["J"] == 4
+    assert out["owners"] == [[i, i] for i in range(4)]
+    assert (out["layers"], out["vocab"], out["d_model"]) == (1, 128, 64)
+    steps = [ln for ln in lines if ln.startswith("step ")]
+    assert len(steps) == 4
+    for ln in steps:
+        loss = float(ln.split()[3])
+        assert loss == loss and abs(loss) < 1e3, ln
+    assert sum("consensus r=" in ln for ln in steps) == 2
+
+
+def test_no_multi_pod_reaches_the_mesh(monkeypatch):
+    from repro.launch import train
+    from repro.launch.mesh import make_mesh
+    assert train.parse_args([]).multi_pod is True
+    seen = []
+
+    def fake_debug_mesh(*, multi_pod):
+        # the debug mesh's axes on the one device this process has
+        seen.append(multi_pod)
+        return make_mesh((1, 1, 1) if multi_pod else (1, 1),
+                         (("pod",) if multi_pod else ()) + ("data", "model"))
+
+    monkeypatch.setattr(train, "make_debug_mesh", fake_debug_mesh)
+    _, tr = train.build_trainer(
+        train.parse_args(["--reduced", "--no-multi-pod"]))
+    assert seen == [False]
+    assert not tr.has_pod and tr.num_nodes == 1
