@@ -100,6 +100,11 @@ class ConsensusConfig:
     obs: ObsConfig | None = None
 
 
+# jitted replica init per (model, AdamW config, J, mesh): trainers that
+# differ only in their round configuration share one compile
+_REPLICA_INIT: dict = {}
+
+
 class TrainState(NamedTuple):
     params: Any            # [J, ...] per-node replicas, P('pod', ...)
     opt: adamw_lib.AdamWState
@@ -196,24 +201,33 @@ class ConsensusTrainer:
     def init_state(self, key: jax.Array) -> TrainState:
         """Fresh state, built already laid out by ``state_shardings``.
 
-        With a pod axis the state is made inside one jit whose outputs are
-        sharded, so each node's replica is created on its own pod's
+        With a pod axis the node replicas (params, AdamW moments) are made
+        inside one jit whose outputs are sharded, and the flat buffers are
+        made sharded, so each node's state is created on its own pod's
         devices; made eagerly, all J replicas would first land on one
         device, which at published widths does not fit.
         """
         if not self.has_pod:
-            return self._init_state(key)
-        return jax.jit(self._init_state,
-                       out_shardings=self.state_shardings())(key)
+            return self._assemble(*self._replicas(key))
+        sh = self.state_shardings()
+        cache_key = (self.model, self.acfg, self.num_nodes, self.mesh)
+        make = _REPLICA_INIT.get(cache_key)
+        if make is None:     # one compile per model, optimizer and mesh
+            make = jax.jit(self._replicas, out_shardings=(sh.params, sh.opt))
+            _REPLICA_INIT[cache_key] = make
+        state = self._assemble(*make(key), flat_sharding=sh.lam)
+        return jax.device_put(state, sh)
 
-    def _init_state(self, key: jax.Array) -> TrainState:
+    def _replicas(self, key: jax.Array):
+        """(params, AdamW state): one init broadcast to the J nodes."""
         with shd.use_mesh(self.mesh, self.inner_rules):
             params1 = self.model.init(key)
-        params = self._node_stack(params1)
         opt1 = adamw_lib.init(self.acfg, params1)
-        opt = adamw_lib.AdamWState(step=opt1.step,
-                                   m=self._node_stack(opt1.m),
-                                   v=self._node_stack(opt1.v))
+        return self._node_stack(params1), adamw_lib.AdamWState(
+            step=opt1.step, m=self._node_stack(opt1.m),
+            v=self._node_stack(opt1.v))
+
+    def _assemble(self, params, opt, flat_sharding=None) -> TrainState:
         # two distinct buffers (never aliased: the state may be donated)
         flat_shape = (self.num_nodes, self.layout.total)
         ledger = None
@@ -222,8 +236,9 @@ class ConsensusTrainer:
                                       self.num_nodes, codec=self.codec)
         return TrainState(
             params=params, opt=opt,
-            lam=jnp.zeros(flat_shape, jnp.float32),
-            theta_bar_prev=jnp.zeros(flat_shape, jnp.float32),
+            lam=jnp.zeros(flat_shape, jnp.float32, device=flat_sharding),
+            theta_bar_prev=jnp.zeros(flat_shape, jnp.float32,
+                                     device=flat_sharding),
             penalty=init_penalty_state(self.ccfg.penalty, self.num_nodes),
             step=jnp.zeros((), jnp.int32),
             topo=self.topo_rt.init_state(),

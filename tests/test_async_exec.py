@@ -19,17 +19,13 @@ Three layers:
         graph where round_robin really gates chords;
   * ledger layer — zero-init is never consumed, buffers hold bytes.
 """
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
 
 from repro.async_exec import RoundClock, straggler_compute
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from script_result import run_result
 
 
 # ---------------------------------------------------------- host layer ----
@@ -361,14 +357,7 @@ print("RESULT " + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def engine_results():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    proc = subprocess.run([sys.executable, "-c", _ENGINE], env=env,
-                          capture_output=True, text=True, timeout=1800)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("RESULT ")][-1]
-    return json.loads(line[len("RESULT "):])
+    return run_result(_ENGINE, timeout=1800)
 
 
 def test_max_staleness_zero_bit_identical_to_sync(engine_results):

@@ -13,14 +13,10 @@ on both paths — the sharded permutes moving per-shard wire slabs — plus
 the per-device HBM contract: each device holds 1/(in-pod size) of the flat
 lam buffer.
 """
-import json
-import os
-import subprocess
-import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from script_result import run_result
 
 _SCRIPT = r"""
 import os
@@ -199,14 +195,7 @@ print("RESULT " + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def fused_results():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
-                          capture_output=True, text=True, timeout=1800)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("RESULT ")][-1]
-    return json.loads(line[len("RESULT "):])
+    return run_result(_SCRIPT, timeout=1800)
 
 
 def test_all_schemes_and_compressions_match(fused_results):

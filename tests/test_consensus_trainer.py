@@ -6,14 +6,10 @@ module is SKIPPED unless the harness exported the flag; tests/conftest.py
 does NOT set it globally per the dry-run spec. A dedicated pytest plugin
 spawns one subprocess for this module instead).
 """
-import json
-import os
-import subprocess
-import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from script_result import run_result
 
 _SCRIPT = r"""
 import os
@@ -83,14 +79,7 @@ print("RESULT " + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def trainer_results():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
-                          capture_output=True, text=True, timeout=1200)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("RESULT ")][-1]
-    return json.loads(line[len("RESULT "):])
+    return run_result(_SCRIPT, timeout=1200)
 
 
 def test_loss_decreases(trainer_results):

@@ -4,14 +4,10 @@ Exercises _compile_step/_corrected_record/lower-cell plumbing with reduced
 configs on a small mesh — the same code paths the production 512-device
 dry-run uses, cheap enough for CI.
 """
-import json
-import os
-import subprocess
-import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from script_result import run_result
 
 _SCRIPT = r"""
 import os
@@ -45,14 +41,7 @@ print("RESULT " + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def recs():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
-                          capture_output=True, text=True, timeout=1800)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("RESULT ")][-1]
-    return json.loads(line[len("RESULT "):])
+    return run_result(_SCRIPT, timeout=1800)
 
 
 def test_all_cells_lower_and_compile(recs):

@@ -30,7 +30,7 @@ def test_flash_attention_matches_ref(b, h, kh, s, hd, causal, window, bq, bk,
     k = jnp.asarray(rng.normal(size=(b, kh, s, hd)), dtype)
     v = jnp.asarray(rng.normal(size=(b, kh, s, hd)), dtype)
     out = fa_raw(q, k, v, causal=causal, window=window, block_q=bq,
-                 block_k=bk)
+                 block_k=bk, interpret=ops.interpret_mode())
     n_rep = h // kh
     kr, vr = jnp.repeat(k, n_rep, 1), jnp.repeat(v, n_rep, 1)
     expect = ref.flash_attention_ref(q, kr, vr, causal=causal, window=window)
@@ -49,7 +49,8 @@ def test_flash_attention_property_sweep():
         q = jnp.asarray(rng.normal(size=(b, h, s, hd)).astype(np.float32))
         k = jnp.asarray(rng.normal(size=(b, kh, s, hd)).astype(np.float32))
         v = jnp.asarray(rng.normal(size=(b, kh, s, hd)).astype(np.float32))
-        out = fa_raw(q, k, v, causal=True, block_q=64, block_k=64)
+        out = fa_raw(q, k, v, causal=True, block_q=64, block_k=64,
+                     interpret=ops.interpret_mode())
         n_rep = h // kh
         expect = ref.flash_attention_ref(q, jnp.repeat(k, n_rep, 1),
                                          jnp.repeat(v, n_rep, 1), causal=True)
@@ -84,7 +85,8 @@ def test_rwkv6_matches_ref(b, h, t, hd, chunk, dtype):
                      jnp.float32)
     u = jnp.asarray(rng.normal(size=(h, hd)) * 0.1, jnp.float32)
     s0 = jnp.asarray(rng.normal(size=(b, h, hd, hd)) * 0.1, jnp.float32)
-    y, sf = rw_raw(r, k, v, lw, u, s0, chunk=chunk)
+    y, sf = rw_raw(r, k, v, lw, u, s0, chunk=chunk,
+                   interpret=ops.interpret_mode())
     yr, sr = ref.rwkv6_scan_ref(r, k, v, lw, u, s0)
     scale = float(np.abs(np.asarray(yr, np.float32)).max()) + 1e-6
     rtol = 3e-5 if dtype == jnp.float32 else 8e-3   # bf16: ~3 digits
@@ -106,8 +108,10 @@ def test_rwkv6_chunk_invariance():
                      .astype(np.float32))
     u = jnp.zeros((h, hd), jnp.float32)
     s0 = jnp.zeros((b, h, hd, hd), jnp.float32)
-    y16, s16 = rw_raw(r, k, v, lw, u, s0, chunk=16)
-    y64, s64 = rw_raw(r, k, v, lw, u, s0, chunk=64)
+    y16, s16 = rw_raw(r, k, v, lw, u, s0, chunk=16,
+                      interpret=ops.interpret_mode())
+    y64, s64 = rw_raw(r, k, v, lw, u, s0, chunk=64,
+                      interpret=ops.interpret_mode())
     np.testing.assert_allclose(np.asarray(y16), np.asarray(y64), atol=2e-4)
     np.testing.assert_allclose(np.asarray(s16), np.asarray(s64), atol=2e-4)
 

@@ -1,12 +1,8 @@
 """MoE expert-parallel path vs dense reference (subprocess: needs 8 devices)."""
-import json
-import os
-import subprocess
-import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from script_result import run_result
 
 _SCRIPT = r"""
 import os
@@ -51,14 +47,7 @@ print("RESULT " + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def ep_results():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
-                          capture_output=True, text=True, timeout=900)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("RESULT ")][-1]
-    return json.loads(line[len("RESULT "):])
+    return run_result(_SCRIPT, timeout=900)
 
 
 def test_a2a_dispatch_matches_reference(ep_results):

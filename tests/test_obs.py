@@ -28,8 +28,6 @@ Four layers:
 """
 import json
 import os
-import subprocess
-import sys
 from types import SimpleNamespace
 
 import numpy as np
@@ -39,7 +37,7 @@ from repro.obs import (ObsConfig, diff_events, drain, drain_rows, init_ring,
                        ring_append, snapshot)
 from repro.obs import schema
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from script_result import run_result
 
 
 # -------------------------------------------------------- schema layer ----
@@ -830,14 +828,7 @@ print("RESULT " + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def engine_results():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    proc = subprocess.run([sys.executable, "-c", _ENGINE], env=env,
-                          capture_output=True, text=True, timeout=1800)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("RESULT ")][-1]
-    return json.loads(line[len("RESULT "):])
+    return run_result(_ENGINE, timeout=1800)
 
 
 def test_obs_off_is_byte_identical_hlo(engine_results):

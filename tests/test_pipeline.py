@@ -22,14 +22,9 @@ for tier 1; the matrix keeps every axis value and the risky pairs.
 Runs on a 4-pod mesh (ring offsets [1, 3]) so depth > 1 is non-trivial, and
 sweeps intermediate bounded depths (2) as well as full depth (>= deg).
 """
-import json
-import os
-import subprocess
-import sys
-
 import pytest
 
-ROOT = os.path.join(os.path.dirname(__file__), os.pardir)
+from script_result import run_result
 
 _SCRIPT = r"""
 import os
@@ -158,14 +153,7 @@ print("RESULT " + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def pipeline_results():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
-                          capture_output=True, text=True, timeout=3000)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("RESULT ")][-1]
-    return json.loads(line[len("RESULT "):])
+    return run_result(_SCRIPT, timeout=3000)
 
 
 def test_matrix_covers_every_axis_value():

@@ -11,10 +11,6 @@ Three layers:
     bit-identical to the PR 1 fused round, and a mid-run node drop on the
     debug mesh completes training without recompiling the fused step.
 """
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import jax.numpy as jnp
@@ -27,7 +23,7 @@ from repro.topology import (SCHEDULERS, TopologyConfig, TopologyRuntime,
 
 from proptest import sweep, draw_topology
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from script_result import run_result
 
 
 def _alive_components(mask, alive):
@@ -393,14 +389,7 @@ print("RESULT " + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def engine_results():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    proc = subprocess.run([sys.executable, "-c", _ENGINE], env=env,
-                          capture_output=True, text=True, timeout=1800)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("RESULT ")][-1]
-    return json.loads(line[len("RESULT "):])
+    return run_result(_ENGINE, timeout=1800)
 
 
 def test_static_scheduler_bit_identical_to_fused_round(engine_results):

@@ -1,24 +1,13 @@
 """End-to-end launcher tests: train -> checkpoint -> crash -> resume,
 straggler handling, and elastic node-drop (subprocess: needs 8 devices)."""
-import json
-import os
-import subprocess
-import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from script_result import run_result
 
 
 def _run(script: str, timeout=1200):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    proc = subprocess.run([sys.executable, "-c", script], env=env,
-                          capture_output=True, text=True, timeout=timeout)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("RESULT ")][-1]
-    return json.loads(line[len("RESULT "):])
+    return run_result(script, timeout=timeout)
 
 
 _RESUME = r"""

@@ -35,14 +35,10 @@ mirroring the int8 staleness pins in test_async_exec.py. Sharded vs
 unsharded stays at f32 exactness (1e-5): per-block scales are slab-local,
 so the sharded engine consumes byte-identical wire.
 """
-import json
-import os
-import subprocess
-import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from script_result import run_result
 
 _PREAMBLE = r"""
 import os
@@ -242,14 +238,7 @@ print("RESULT " + json.dumps(out))
 
 
 def _run(script):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    proc = subprocess.run([sys.executable, "-c", script], env=env,
-                          capture_output=True, text=True, timeout=1800)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("RESULT ")][-1]
-    return json.loads(line[len("RESULT "):])
+    return run_result(script, timeout=1800)
 
 
 @pytest.fixture(scope="module")
