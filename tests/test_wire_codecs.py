@@ -1,9 +1,10 @@
 """Engine pins for the fp8 wire codecs (repro.wire): fused == reference.
 
-Two subprocesses on the 8-fake-device mesh (J=4 pods, 2-way in-pod
+Three subprocesses on the 8-fake-device mesh (J=4 pods, 2-way in-pod
 sharding) sweep fused-vs-reference and sharded-vs-unsharded rounds with
-the fp8 codecs across the gating modes (split in two so each stays well
-inside the CI subprocess timeout on 2-core runners):
+the fp8 codecs across the gating modes (split in three so each stays well
+inside the CI subprocess timeout on 2-core runners, and so pytest-xdist
+workers can run them side by side):
 
   * ``static`` (subprocess A) — all 6 penalty schemes x both fp8 codecs x
     {reference, fused, fused+sharded}, one sync round each at f32
@@ -15,7 +16,7 @@ inside the CI subprocess timeout on 2-core runners):
     budget-capable schemes (nap, vp_nap — the budget scheduler REJECTS
     non-budget penalties by construction, so the other four schemes
     cannot run this mode), e4m3 on all three paths + an e5m2 spot check;
-  * ``stale`` (subprocess B) — bounded-staleness async rounds (complete
+  * ``stale`` (subprocess C) — bounded-staleness async rounds (complete
     graph, sender 0 lands only at tick 0 => its edges age 0,1,2 and gate
     with an in-round ledger zero-kick at tick 2) for all 6 schemes with
     fp8_e4m3 {ref, fused} + sharded and e5m2 spot checks. The two fp8
@@ -173,7 +174,7 @@ for codec in FP8:
 print("RESULT " + json.dumps(out))
 """
 
-_GATED = _PREAMBLE + r"""
+_BUDGET = _PREAMBLE + r"""
 # --- budget-gated: forced exhaustion on the complete graph ---------------
 # (budget-capable schemes only: the scheduler validates uses_budget)
 out["budget"] = {}
@@ -192,7 +193,10 @@ for scheme, codec, with_sharded in budget_grid:
         shd = sync_rounds(make(codec, scheme, sharded=True, **kw))
         rec["sharded_vs_fused"] = errs(shd, fus)
     out["budget"][f"{scheme}_{codec}"] = rec
+print("RESULT " + json.dumps(out))
+"""
 
+_STALE = _PREAMBLE + r"""
 # --- stale: bounded-staleness gating + in-round ledger kick --------------
 def arrivals_for(tr, tick):
     deg = len(tr.offsets)
@@ -246,9 +250,16 @@ def static_results():
     return _run(_STATIC)
 
 
+# budget and stale sweeps run as separate scripts so two workers can run
+# them at once
 @pytest.fixture(scope="module")
-def gated_results():
-    return _run(_GATED)
+def budget_results():
+    return _run(_BUDGET)
+
+
+@pytest.fixture(scope="module")
+def stale_results():
+    return _run(_STALE)
 
 
 def test_static_fp8_fused_matches_reference_all_schemes(static_results):
@@ -289,11 +300,11 @@ def test_fp8_roofline_wire_bytes_shrink(static_results):
         assert rec["roofline_row_bytes"] < rec["native_row_bytes"], rec
 
 
-def test_budget_gated_fp8_fused_matches_reference(gated_results):
+def test_budget_gated_fp8_fused_matches_reference(budget_results):
     """Forced-exhaustion budget gating (gate + parked-kick absorption)
     through the fp8 wire: fused == reference at wire precision, sharded ==
     unsharded at f32 exactness, and gating actually fired."""
-    cases = gated_results["budget"]
+    cases = budget_results["budget"]
     assert len(cases) == 3, sorted(cases)
     for k, v in cases.items():
         assert v["gated"], (k, v)
@@ -303,11 +314,11 @@ def test_budget_gated_fp8_fused_matches_reference(gated_results):
             assert v["sharded_vs_fused"]["max_err"] <= 1e-5, (k, v)
 
 
-def test_stale_fp8_fused_matches_reference(gated_results):
+def test_stale_fp8_fused_matches_reference(stale_results):
     """Bounded-staleness rounds (ledger fallback, staleness gating,
     in-round zero-kick) through the fp8 wire: fused == reference at the
     documented wire precision for all 6 schemes."""
-    cases = gated_results["stale"]
+    cases = stale_results["stale"]
     assert len(cases) == 7, sorted(cases)
     for k, v in cases.items():
         assert v["gating_seen"], (k, v)
@@ -315,11 +326,11 @@ def test_stale_fp8_fused_matches_reference(gated_results):
         assert v["fused_vs_ref"]["metric_rel_err"] < 1e-2, (k, v)
 
 
-def test_stale_fp8_sharded_matches_unsharded(gated_results):
+def test_stale_fp8_sharded_matches_unsharded(stale_results):
     """The sharded stale round (per-shard fp8 ledger rows, slab-local
     scale decode) == the unsharded fused round at f32 exactness."""
-    cases = {k: v for k, v in gated_results["stale"].items()
+    cases = {k: v for k, v in stale_results["stale"].items()
              if "sharded_vs_fused" in v}
-    assert len(cases) == 2, sorted(gated_results["stale"])
+    assert len(cases) == 2, sorted(stale_results["stale"])
     for k, v in cases.items():
         assert v["sharded_vs_fused"]["max_err"] <= 1e-5, (k, v)
