@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.async_exec.clock import RoundClock
-from repro.obs.trace import host_span_factory
 
 
 class AsyncExecutor:
@@ -49,8 +48,6 @@ class AsyncExecutor:
                              f"trainer has {trainer.num_nodes}")
         self.clock = clock
         self._cons = trainer.jit_async_step_fns()
-        self._hspan = host_span_factory(
-            trainer.obs_on and trainer.obs_cfg.with_spans)
 
     # ------------------------------------------------------------ state ----
     def init_state(self, key: jax.Array):
@@ -75,9 +72,7 @@ class AsyncExecutor:
             arr_np, adv_np = self.clock.tick()
             arrivals = jnp.asarray(arr_np)
             advance = jnp.asarray(adv_np)
-        with self._hspan("round/async"):
-            state, metrics = self._cons(state, probe_batch, arrivals, advance)
-        return state, metrics
+        return self._cons(state, probe_batch, arrivals, advance)
 
     # ------------------------------------------------------- accounting ----
     @property

@@ -368,6 +368,7 @@ def _row_round(theta, lam, bar_prev, wires, scales, e_sym, node_scalars,
         kernel,
         grid=(j,),
         in_specs=in_specs,
+        name="consensus_round",
         out_specs=[vec, vec, vec,
                    pl.BlockSpec((1, 1), lambda i: (i, 0)),
                    pl.BlockSpec((1, 1), lambda i: (i, 0))],
@@ -512,6 +513,8 @@ def consensus_round(theta, lam, bar_prev, wires, scales, e_sym,
         kernel,
         grid=(j, nblocks),
         in_specs=in_specs,
+        # a fixed name: the benchmark's trace readers find the kernel by it
+        name="consensus_round",
         out_specs=[vec, vec, vec, part],
         out_shape=[
             jax.ShapeDtypeStruct((j, total), theta.dtype),

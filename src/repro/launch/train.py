@@ -33,11 +33,11 @@ from repro.launch.mesh import (gpu_plugin_installed, make_debug_mesh,
                                make_local_mesh, make_production_mesh,
                                set_backend_flags)
 from repro.models import build_model
-from repro.obs import ObsConfig, ObsWriter, host_span_factory
+from repro.obs import ObsConfig, ObsWriter
 from repro.optim import ConsensusConfig, ConsensusTrainer
 from repro.optim.adamw import AdamWConfig
-from repro.runtime import (ElasticController, RetryPolicy, StragglerMonitor,
-                           aged_out_nodes, with_retries)
+from repro.runtime import (ElasticController, RetryPolicy, aged_out_nodes,
+                           with_retries)
 from repro.runtime.compile_cache import enable_compile_cache
 from repro.topology import SCHEDULERS as TOPO_SCHEDULERS, TopologyConfig
 
@@ -78,9 +78,10 @@ def parse_args(argv=None):
                     help="STEP:VICTIM — simulate losing pod VICTIM after "
                          "STEP (debug-mesh churn drill; implies --topo-churn)")
     ap.add_argument("--drop-stragglers", action="store_true",
-                    help="ghost a flagged straggler pod via the topology "
-                         "runtime instead of just logging it (async mode "
-                         "flags by edge age, sync mode by wall-clock EMA)")
+                    help="ghost a straggler pod via the topology runtime: "
+                         "one whose edges aged out of the async executor's "
+                         "staleness bound (--async; sync rounds flag "
+                         "nothing)")
     ap.add_argument("--async", dest="async_mode", action="store_true",
                     help="bounded-staleness executor (repro.async_exec): "
                          "consensus rounds consume the freshest LANDED "
@@ -149,8 +150,8 @@ def parse_args(argv=None):
     ap.add_argument("--profile-rounds", type=int, default=0,
                     help="capture a jax profiler trace covering the first "
                          "N consensus rounds into <obs-dir>/profile "
-                         "(view in Perfetto/TensorBoard; the obs trace "
-                         "spans label the round phases)")
+                         "(view in Perfetto/TensorBoard; the obs.trace "
+                         "scopes label the phases)")
     args = ap.parse_args(argv)
     if args.health and not args.obs_dir:
         ap.error("--health requires --obs-dir (the monitor feeds off "
@@ -266,7 +267,6 @@ def main(argv=None):
         executor = AsyncExecutor(trainer, RoundClock(
             compute_s=compute, wire_s=0.25,
             offsets=tuple(trainer.offsets)))
-    monitor = StragglerMonitor(trainer.num_nodes)
     elastic = ElasticController(trainer.graph, topology=trainer.topo_rt)
     step_fn = with_retries(train, RetryPolicy())
 
@@ -284,8 +284,8 @@ def main(argv=None):
             "drain_every": args.obs_drain_every,
         }, max_staleness=(args.max_staleness if args.async_mode else None),
             health=args.health)
-    round_span = host_span_factory(writer is not None)
     rounds, profiling = 0, False
+    round_name = "round/async" if executor is not None else "round/sync"
 
     def make_batch(step):
         if cfg.frontend != "none":
@@ -294,76 +294,75 @@ def main(argv=None):
 
     t_start = time.time()
     for step in range(start_step, args.steps):
-        t0 = time.time()
-        batch = make_batch(step)
-        state, m = step_fn(state, batch)
-        jax.block_until_ready(m["loss"])
-        dt = time.time() - t0
-        slow = monitor.observe(np.full(trainer.num_nodes, dt))
-        line = f"step {step:5d} loss {float(m['loss']):.4f} {dt*1e3:.0f}ms"
-        if trainer.should_sync(step):
-            probe = make_batch(10**6 + step)
-            if args.profile_rounds > 0 and rounds == 0 and not profiling:
-                jax.profiler.start_trace(
-                    os.path.join(args.obs_dir or ".", "profile"))
-                profiling = True
-            with round_span("round/async" if executor is not None
-                            else "round/sync"):
-                if executor is not None:
-                    state, cm = executor.consensus_round(state, probe)
-                else:
-                    state, cm = cons(state, probe)
-            rounds += 1
-            if profiling and rounds >= args.profile_rounds:
-                jax.block_until_ready(cm["r_max"])
-                jax.profiler.stop_trace()
-                profiling = False
-                print(f"profile trace ({args.profile_rounds} rounds) -> "
-                      f"{os.path.join(args.obs_dir or '.', 'profile')}",
-                      flush=True)
-            if writer is not None and rounds % args.obs_drain_every == 0:
-                writer.drain(state, step=step + 1)
-            line += (f" | consensus r={float(cm['r_max']):.4f} "
-                     f"eta={float(cm['eta_mean']):.4f}")
-            if trainer.dynamic:
-                line += f" active={float(cm['active_edges']):.2f}"
-            if executor is not None and "stale_edges" in cm:
-                line += (f" stale={float(cm['stale_edges']):.2f}"
-                         f" age_max={int(cm['age_max'])}")
-            if executor is not None and args.drop_stragglers:
-                # async unification: the staleness clocks ARE the
-                # straggler signal — wall-clock EMA not needed
-                for v in aged_out_nodes(
-                        state.topo, max_staleness=args.max_staleness):
-                    alive = np.asarray(state.topo.node_alive)
-                    if alive[v] and alive.sum() > 2:
-                        state = state._replace(topo=elastic.drop_preserving(
-                            v, state.topo, step))
-                        line += f" | ghosted aged-out node {v}"
-        if step == drop_at:
-            # layout-preserving churn drill: ghost the victim, keep going —
-            # same compiled step fns, no restart (a topology epoch)
-            state = state._replace(topo=elastic.drop_preserving(
-                drop_victim, state.topo, step))
-            line += f" | dropped node {drop_victim} (topology epoch)"
-        if slow and executor is None:
-            line += f" | stragglers: {slow}"
-            if args.drop_stragglers and trainer.dynamic:
-                for v in slow:
-                    # re-read liveness each drop: several stragglers may be
-                    # flagged in one step and the >2-survivors floor must
-                    # see the drops already applied
-                    alive = np.asarray(state.topo.node_alive)
-                    if alive[v] and alive.sum() > 2:
-                        state = state._replace(topo=elastic.drop_preserving(
-                            v, state.topo, step))
-                        line += f" | ghosted straggler {v}"
-        print(line, flush=True)
-        if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
-            save_async(args.ckpt_dir, step + 1, state,
-                       metadata={"step": step + 1, "arch": cfg.arch_id,
-                                 "scheme": args.scheme,
-                                 "topology": args.topology})
+        # host spans on the profiler's clock (docs/observability.md): every
+        # span of one step shares its step_num; what the step spends outside
+        # its children (formatting, printing, the checkpoint hook) is the
+        # step span's own time
+        with jax.profiler.StepTraceAnnotation("train/step", step_num=step):
+            t0 = time.time()
+            with jax.profiler.TraceAnnotation("train/batch"):
+                batch = make_batch(step)
+            with jax.profiler.TraceAnnotation("train/dispatch"):
+                state, m = step_fn(state, batch)
+            with jax.profiler.TraceAnnotation("train/wait"):
+                jax.block_until_ready(m["loss"])
+                dt = time.time() - t0
+                loss = float(m["loss"])
+            line = f"step {step:5d} loss {loss:.4f} {dt*1e3:.0f}ms"
+            if trainer.should_sync(step):
+                with jax.profiler.TraceAnnotation("train/batch"):
+                    probe = make_batch(10**6 + step)
+                if args.profile_rounds > 0 and rounds == 0 and not profiling:
+                    jax.profiler.start_trace(
+                        os.path.join(args.obs_dir or ".", "profile"))
+                    profiling = True
+                with jax.profiler.TraceAnnotation(round_name):
+                    if executor is not None:
+                        state, cm = executor.consensus_round(state, probe)
+                    else:
+                        state, cm = cons(state, probe)
+                rounds += 1
+                if profiling and rounds >= args.profile_rounds:
+                    jax.block_until_ready(cm["r_max"])
+                    jax.profiler.stop_trace()
+                    profiling = False
+                    print(f"profile trace ({args.profile_rounds} rounds) -> "
+                          f"{os.path.join(args.obs_dir or '.', 'profile')}",
+                          flush=True)
+                if writer is not None and rounds % args.obs_drain_every == 0:
+                    writer.drain(state, step=step + 1)
+                with jax.profiler.TraceAnnotation("train/wait"):
+                    line += (f" | consensus r={float(cm['r_max']):.4f} "
+                             f"eta={float(cm['eta_mean']):.4f}")
+                    if trainer.dynamic:
+                        line += f" active={float(cm['active_edges']):.2f}"
+                    if executor is not None and "stale_edges" in cm:
+                        line += (f" stale={float(cm['stale_edges']):.2f}"
+                                 f" age_max={int(cm['age_max'])}")
+                if executor is not None and args.drop_stragglers:
+                    # the staleness clocks ARE the straggler signal: a
+                    # node whose edges aged out is ghosted
+                    for v in aged_out_nodes(
+                            state.topo, max_staleness=args.max_staleness):
+                        alive = np.asarray(state.topo.node_alive)
+                        if alive[v] and alive.sum() > 2:
+                            state = state._replace(
+                                topo=elastic.drop_preserving(v, state.topo,
+                                                             step))
+                            line += f" | ghosted aged-out node {v}"
+            if step == drop_at:
+                # layout-preserving churn drill: ghost the victim, keep
+                # going — same compiled step fns, no restart (a topology
+                # epoch)
+                state = state._replace(topo=elastic.drop_preserving(
+                    drop_victim, state.topo, step))
+                line += f" | dropped node {drop_victim} (topology epoch)"
+            print(line, flush=True)
+            if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
+                save_async(args.ckpt_dir, step + 1, state,
+                           metadata={"step": step + 1, "arch": cfg.arch_id,
+                                     "scheme": args.scheme,
+                                     "topology": args.topology})
     wait_pending()
     print(f"done: {args.steps - start_step} steps in "
           f"{time.time() - t_start:.1f}s")

@@ -12,8 +12,8 @@ Pieces, one per telemetry concern (details in each module and in
   * ``obs.node_ring`` — the per-node ``[cap, J, n_cols]`` telemetry ring
     next to it: per-node residuals, objective, penalty row means,
     staleness ages, liveness and wire bytes.
-  * ``obs.trace``     — ``jax.named_scope`` / profiler-annotation span
-    factories with the round-phase naming convention.
+  * ``obs.trace``     — ``span``: the ``jax.named_scope`` with the
+    local-step and round-phase naming convention, always compiled in.
   * ``obs.journal``   — host-side JSONL event journal derived by diffing
     drained ``TopologyState``/``PenaltyState`` snapshots (plus raw
     ``emit`` for health events).
@@ -26,10 +26,11 @@ Pieces, one per telemetry concern (details in each module and in
   * ``obs.dashboard`` — renders one obs directory into a single
     self-contained HTML dashboard (``python -m repro.obs.dashboard``).
 
-Everything is off by default and leaves zero trace in compiled code when
-off: ``ConsensusConfig.obs=None`` (or ``ObsConfig(enabled=False)``) lowers
-byte-identical HLO to a build without the subsystem (pinned in
-``tests/test_obs.py``); ``ObsConfig(with_node_ring=False)`` compiles the
+The rings are off by default and add nothing to the compiled step when
+off: ``ConsensusConfig.obs=None`` and ``ObsConfig(enabled=False)`` lower
+byte-identical HLO with no ring in it (pinned in ``tests/test_obs.py``);
+the ``obs.trace`` scopes are op metadata, always compiled in.
+``ObsConfig(with_node_ring=False)`` compiles the
 node ring out while keeping the scalar ring.
 """
 from repro.obs.export import (ObsWriter, build_rollup,
@@ -48,8 +49,7 @@ from repro.obs.schema import (COLUMN_INDEX, NODE_COLUMN_INDEX, NODE_COLUMNS,
                               decode_step, encode_step, metrics_row,
                               node_row, node_row_to_dict, row_to_dict,
                               unify_node_metrics, unify_round_metrics)
-from repro.obs.trace import (host_span, host_span_factory, span,
-                             span_factory)
+from repro.obs.trace import span
 
 __all__ = [
     "COLUMN_INDEX", "EventJournal", "HEALTH_EVENTS", "HealthConfig",
@@ -58,9 +58,9 @@ __all__ = [
     "ObsConfig", "ObsWriter", "RING_COLUMNS", "ROUND_METRICS",
     "SCHEMA_VERSION", "analyze_trace", "build_rollup", "decode_step",
     "diff_events", "drain", "drain_node_rows", "drain_rows", "encode_step",
-    "host_span", "host_span_factory", "init_node_ring", "init_ring",
+    "init_node_ring", "init_ring",
     "metrics_row", "node_ring_append", "node_row", "node_row_to_dict",
     "ring_append", "roundclock_trace_events", "row_to_dict", "snapshot",
-    "span", "span_factory", "unify_node_metrics", "unify_round_metrics",
+    "span", "unify_node_metrics", "unify_round_metrics",
     "validate_obs_dir", "write_roundclock_trace",
 ]

@@ -41,16 +41,13 @@ class ObsConfig:
 
     Attributes:
       enabled: master switch. ``ObsConfig(enabled=False)`` is pinned to
-        lower BYTE-IDENTICAL HLO to ``obs=None`` — the subsystem leaves
-        zero trace in the compiled step when off (tests/test_obs.py).
+        lower BYTE-IDENTICAL HLO to ``obs=None`` — no ring in the
+        compiled step when off (tests/test_obs.py).
       ring_capacity: rows in the on-device metrics ring. Must be >=
         ``drain_every`` or steady-state drains drop rows (allowed but
         reported).
       drain_every: host drain cadence in CONSENSUS ROUNDS (the K of the
         amortized-drain accounting in ``launch.dryrun``).
-      with_spans: wrap the traced round phases (pack, permute, decode,
-        probe, fused kernel) in ``jax.named_scope`` spans and the host
-        round calls in profiler TraceAnnotations (``obs.trace``).
       with_node_ring: carry the per-node telemetry ring
         (``obs.node_ring``: ``[cap, J, NODE_COLUMNS]``) next to the
         scalar ring — per-node residuals, objective, penalty row means,
@@ -63,7 +60,6 @@ class ObsConfig:
     enabled: bool = True
     ring_capacity: int = 256
     drain_every: int = 8
-    with_spans: bool = True
     with_node_ring: bool = True
 
     def __post_init__(self):

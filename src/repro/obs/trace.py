@@ -1,35 +1,35 @@
-"""Trace spans for the consensus round — one naming convention, two layers.
+"""Trace spans for the local step and the consensus round.
 
-Two span kinds, matching where the code runs:
+``span(name)`` is a ``jax.named_scope``: the name lands in the op metadata
+(``op_name``) of every HLO instruction traced under it, so a profiler
+trace groups the compiled ops under readable phases. It is metadata only,
+with no run-time cost, and is always compiled in.
 
-  * ``span(name)`` — TRACED code (inside jit): a ``jax.named_scope``. The
-    scope name lands in the lowered HLO op metadata, so a jax profiler
-    trace (``--profile-rounds``) groups the round's ops under readable
-    phases instead of a flat op soup. Zero runtime cost — metadata only.
-  * ``host_span(name)`` — HOST code (the executor/launcher round loop): a
-    ``jax.profiler.TraceAnnotation``, visible on the python thread track
-    of the same profile.
+Span naming convention (documented in ``docs/observability.md``, read by
+trace viewers as a hierarchy on ``/``). Every op of ``train_step`` lies
+under one ``local/*`` phase and every op of the synchronous
+``consensus_step`` under one ``consensus/*`` phase:
 
-Span naming convention (documented in ``docs/observability.md``, consumed
-by trace viewers as a hierarchy on ``/``):
-
-    consensus/pack            flat-buffer pack + wire encode
+    local/grad                the loss and its gradient (forward and
+                              backward; a backward op's path has
+                              ``transpose(``)
+    local/optimizer           AdamW: clip, moments, decay, step counter
+    consensus/pack            flat-buffer pack + wire encode, and the
+                              unpack of the new parameters
     consensus/exchange/off<k> one graph offset's collective-permute+decode
     consensus/probe           objective probes f_i(theta_j)
-    consensus/fused_round     the fused Pallas call (+ residual psum)
-    consensus/penalty         penalty + topology update
+    consensus/fused_round     the fused Pallas call, its operands and the
+                              residual norms
+    consensus/penalty         penalty + topology update, round metrics
     wire/encode  wire/decode  codec work inside the phases above
-    round/sync  round/async   host-side whole-round annotations
+    obs/ring                  metrics-ring appends (obs on only)
 
-Spans are built through ``span_factory(enabled)`` so the obs-off path gets
-``nullcontext`` factories — with observability disabled the lowered HLO is
-byte-identical to pre-obs code (named_scope changes metadata, which IS
-part of the lowered text, so it must be gated too; pinned in
-``tests/test_obs.py``).
+The launcher's host spans (``jax.profiler`` annotations on the same
+trace) are named in ``launch/train.py``: ``train/step`` with its
+``step_num``, ``train/batch``, ``train/dispatch``, ``train/wait`` and
+``round/sync`` or ``round/async``.
 """
 from __future__ import annotations
-
-import contextlib
 
 import jax
 
@@ -37,24 +37,3 @@ import jax
 def span(name: str):
     """Named scope for traced code; nests under the active scope."""
     return jax.named_scope(name)
-
-
-def host_span(name: str):
-    """Profiler annotation for host-side code (python thread track)."""
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover — profiler unavailable
-        return contextlib.nullcontext()
-
-
-def _null_span(name: str):
-    return contextlib.nullcontext()
-
-
-def span_factory(enabled: bool):
-    """Returns the traced-span factory: ``span`` when on, nullcontext off."""
-    return span if enabled else _null_span
-
-
-def host_span_factory(enabled: bool):
-    return host_span if enabled else _null_span

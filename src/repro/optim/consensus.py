@@ -48,8 +48,8 @@ from repro.kernels import ref as kref
 from repro.obs import node_ring as obs_node_ring
 from repro.obs import ring as obs_ring
 from repro.obs import schema as obs_schema
-from repro.obs import trace as obs_trace
 from repro.obs.ring import ObsConfig
+from repro.obs.trace import span
 from repro.optim import adamw as adamw_lib
 from repro.optim import flatten
 from repro.topology import (TopologyConfig, TopologyRuntime, TopologyState,
@@ -94,9 +94,8 @@ class ConsensusConfig:
     # depth only widens the window the latency-hiding scheduler may
     # overlap. Pair with launch.mesh.set_backend_flags().
     pipeline_offsets: int = 1
-    # observability (repro.obs): the on-device metrics ring + trace spans.
-    # None (and ObsConfig(enabled=False)) leaves the compiled step
-    # byte-identical to a build without the subsystem
+    # observability (repro.obs): the on-device metrics rings. None (and
+    # ObsConfig(enabled=False)) compiles no ring into the step
     obs: ObsConfig | None = None
 
 
@@ -183,14 +182,11 @@ class ConsensusTrainer:
         self.codec = wire_lib.get_codec(self.codec_name, self.layout,
                                         self.slayout)
         self.dequant_spec = self.codec.kernel_dequant_spec()
-        # observability (repro.obs): the metrics ring rides in TrainState
-        # and trace spans wrap the round phases — both fully gated, so an
-        # obs-off trainer lowers byte-identical HLO (tests/test_obs.py)
+        # observability (repro.obs): the metrics rings ride in TrainState,
+        # gated, so an obs-off trainer compiles no ring (tests/test_obs.py)
         self.obs_cfg = consensus.obs
         self.obs_on = self.obs_cfg is not None and self.obs_cfg.enabled
         self.node_ring_on = self.obs_on and self.obs_cfg.with_node_ring
-        self._span = obs_trace.span_factory(
-            self.obs_on and self.obs_cfg.with_spans)
 
     # ------------------------------------------------------------ state ----
     def _node_stack(self, tree):
@@ -368,29 +364,33 @@ class ConsensusTrainer:
 
     def train_step(self, state: TrainState, batch: Any
                    ) -> tuple[TrainState, dict]:
-        """One local optimizer step on every node (no cross-pod traffic)."""
-        if not self.has_pod:
-            def step1(params, opt, batch):
-                (loss, _), grads = jax.value_and_grad(
-                    self._local_loss, has_aux=True)(params, batch)
-                p, o, m = adamw_lib.update(self.acfg, opt, params, grads)
-                return p, o, loss, m["grad_norm"]
+        """One local optimizer step on every node (no cross-pod traffic).
 
-            p1 = jax.tree_util.tree_map(lambda x: x[0], state.params)
-            o1 = adamw_lib.AdamWState(
-                step=state.opt.step,
-                m=jax.tree_util.tree_map(lambda x: x[0], state.opt.m),
-                v=jax.tree_util.tree_map(lambda x: x[0], state.opt.v))
-            b1 = jax.tree_util.tree_map(lambda x: x[0], batch)
-            p, o, loss, gn = step1(p1, o1, b1)
-            new = state._replace(
-                params=jax.tree_util.tree_map(lambda x: x[None], p),
-                opt=adamw_lib.AdamWState(
-                    step=o.step,
-                    m=jax.tree_util.tree_map(lambda x: x[None], o.m),
-                    v=jax.tree_util.tree_map(lambda x: x[None], o.v)),
-                step=state.step + 1)
-            return new, {"loss": loss, "grad_norm": gn}
+        Every op lies under one of two scopes (``obs.trace``):
+        ``local/grad`` (the loss and its gradient) or ``local/optimizer``
+        (AdamW and the step counters).
+        """
+        if not self.has_pod:
+            first = lambda t: jax.tree_util.tree_map(  # noqa: E731
+                lambda x: x[0], t)
+            lead = lambda t: jax.tree_util.tree_map(  # noqa: E731
+                lambda x: x[None], t)
+            with span("local/grad"):
+                (loss, _), grads = jax.value_and_grad(
+                    self._local_loss, has_aux=True)(first(state.params),
+                                                    first(batch))
+            with span("local/optimizer"):
+                o1 = adamw_lib.AdamWState(step=state.opt.step,
+                                          m=first(state.opt.m),
+                                          v=first(state.opt.v))
+                p, o, m = adamw_lib.update(self.acfg, o1,
+                                           first(state.params), grads)
+                new = state._replace(
+                    params=lead(p),
+                    opt=adamw_lib.AdamWState(step=o.step, m=lead(o.m),
+                                             v=lead(o.v)),
+                    step=state.step + 1)
+            return new, {"loss": loss, "grad_norm": m["grad_norm"]}
 
         # vmap over the node axis: per-node loss/grad/update with NO cross-pod
         # communication (GSPMD shards the leading axis on 'pod'). vmap is
@@ -399,43 +399,52 @@ class ConsensusTrainer:
         # shard_map has no vmap batching rule); a production multi-pod MoE
         # deployment runs per-pod controllers instead (DESIGN.md §5).
         def one_node(params, m, v, opt_step, batch):
-            (loss, _), grads = jax.value_and_grad(
-                self._local_loss, has_aux=True)(params, batch)
-            if self.ccfg.grad_rs:
-                with shd.use_mesh(self.mesh, self.inner_rules):
-                    pspec = self.model.param_specs()
-                grads = jax.tree_util.tree_map(
-                    lambda g, s: jax.lax.with_sharding_constraint(
-                        g, NamedSharding(self.mesh, s)),
-                    grads, pspec)
-            opt = adamw_lib.AdamWState(step=opt_step, m=m, v=v)
-            p_new, opt_new, mtr = adamw_lib.update(self.acfg, opt, params,
-                                                   grads)
+            with span("local/grad"):
+                (loss, _), grads = jax.value_and_grad(
+                    self._local_loss, has_aux=True)(params, batch)
+                if self.ccfg.grad_rs:
+                    with shd.use_mesh(self.mesh, self.inner_rules):
+                        pspec = self.model.param_specs()
+                    grads = jax.tree_util.tree_map(
+                        lambda g, s: jax.lax.with_sharding_constraint(
+                            g, NamedSharding(self.mesh, s)),
+                        grads, pspec)
+            with span("local/optimizer"):
+                opt = adamw_lib.AdamWState(step=opt_step, m=m, v=v)
+                p_new, opt_new, mtr = adamw_lib.update(self.acfg, opt,
+                                                       params, grads)
             return p_new, opt_new.m, opt_new.v, loss, mtr["grad_norm"]
 
         if self.model.cfg.moe is not None:
             outs = []
             for i in range(self.num_nodes):
                 sl = lambda t: jax.tree_util.tree_map(lambda x: x[i], t)
-                outs.append(one_node(sl(state.params), sl(state.opt.m),
-                                     sl(state.opt.v), state.opt.step,
-                                     sl(batch)))
+                with span("local/grad"):
+                    p_i, b_i = sl(state.params), sl(batch)
+                with span("local/optimizer"):
+                    m_i, v_i = sl(state.opt.m), sl(state.opt.v)
+                outs.append(one_node(p_i, m_i, v_i, state.opt.step, b_i))
             stack = lambda k: jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs), *[o[k] for o in outs])
-            p_new, m_new, v_new = stack(0), stack(1), stack(2)
-            loss = jnp.stack([o[3] for o in outs])
-            gn = jnp.stack([o[4] for o in outs])
+            with span("local/optimizer"):
+                p_new, m_new, v_new = stack(0), stack(1), stack(2)
+                gn = jnp.stack([o[4] for o in outs])
+            with span("local/grad"):
+                loss = jnp.stack([o[3] for o in outs])
         else:
             p_new, m_new, v_new, loss, gn = jax.vmap(
                 one_node, in_axes=(0, 0, 0, None, 0))(
                 state.params, state.opt.m, state.opt.v, state.opt.step,
                 batch)
-        new = state._replace(
-            params=p_new,
-            opt=adamw_lib.AdamWState(step=state.opt.step + 1, m=m_new,
-                                     v=v_new),
-            step=state.step + 1)
-        return new, {"loss": loss.mean(), "grad_norm": gn}
+        with span("local/optimizer"):
+            new = state._replace(
+                params=p_new,
+                opt=adamw_lib.AdamWState(step=state.opt.step + 1, m=m_new,
+                                         v=v_new),
+                step=state.step + 1)
+        with span("local/grad"):
+            loss = loss.mean()
+        return new, {"loss": loss, "grad_norm": gn}
 
     # --------------------------------------------------- consensus round ----
     def _probe_vloss(self):
@@ -478,14 +487,16 @@ class ConsensusTrainer:
         """
         metrics = obs_schema.unify_round_metrics(metrics)
         if self.obs_on and new.ring is not None:
-            row = obs_schema.metrics_row(new.step, metrics)
-            new = new._replace(ring=obs_ring.ring_append(new.ring, row))
+            with span("obs/ring"):
+                row = obs_schema.metrics_row(new.step, metrics)
+                new = new._replace(ring=obs_ring.ring_append(new.ring, row))
         if self.node_ring_on and new.node_ring is not None:
-            nrow = obs_schema.node_row(new.step, node_metrics or {},
-                                       self.num_nodes)
-            new = new._replace(
-                node_ring=obs_node_ring.node_ring_append(new.node_ring,
-                                                         nrow))
+            with span("obs/ring"):
+                nrow = obs_schema.node_row(new.step, node_metrics or {},
+                                           self.num_nodes)
+                new = new._replace(
+                    node_ring=obs_node_ring.node_ring_append(
+                        new.node_ring, nrow))
         return new, metrics
 
     def _flat_pspec(self, ndim: int = 2) -> P:
@@ -523,7 +534,7 @@ class ConsensusTrainer:
         (see ``docs/wire_formats.md``), pinned to the engine's flat
         sharding so each device encodes only its slab.
         """
-        with self._span("wire/encode"):
+        with span("wire/encode"):
             wire = self.codec.encode(theta_flat)
         if self.sharded:
             return self._constrain_flat(wire)
@@ -536,7 +547,7 @@ class ConsensusTrainer:
         num_blocks for the fp8 per-block scales (which shard with the
         slabs — slab-local decode, no in-pod broadcast).
         """
-        with self._span("wire/decode"):
+        with span("wire/decode"):
             payload, scales = self.codec.decode(wire)
         if self.sharded:
             payload = self._constrain_flat(payload)
@@ -641,8 +652,7 @@ class ConsensusTrainer:
         fn = shd.shard_map(
             local, self.mesh, in_specs=in_specs,
             out_specs=(flat_spec, flat_spec, flat_spec, pod, pod))
-        with self._span("consensus/fused_round"):
-            return fn(*args)
+        return fn(*args)
 
     def consensus_step(self, state: TrainState, probe_batch: Any
                        ) -> tuple[TrainState, dict]:
@@ -675,27 +685,28 @@ class ConsensusTrainer:
         deg = len(offsets)
         adj = jnp.asarray(self.graph.adj)
         pcfg = self.ccfg.penalty
-        idx = jnp.arange(j)
+        with span("consensus/fused_round"):     # indexes the edge weights
+            idx = jnp.arange(j)
         lay = self.layout
         dynamic = self.dynamic
 
         vloss = self._probe_vloss()
 
+        # every op lies under one of the five consensus/* scopes
+        # (obs.trace), or under obs/ring when the rings are on
         # probe own objective (pre-update params, eq. 7 semantics)
-        with self._span("consensus/probe"):
+        with span("consensus/probe"):
             f_self = vloss(state.params, probe_batch)          # [J]
+            f_nbr = jnp.zeros((j, j), jnp.float32)
 
         # pack in the params' native float dtype: the uncompressed wire then
         # moves the same bytes/param as the old per-leaf exchange (bf16 = 2B)
-        with self._span("consensus/pack"):
+        with span("consensus/pack"):
             theta_flat = self._constrain_flat(
                 lay.pack(state.params, dtype=lay.wire_dtype))
             wire = self._encode_wire(theta_flat)
 
         eta = state.penalty.eta
-        ones = jnp.ones((j, self.dequant_spec.scale_width), jnp.float32)
-        sym_sum = jnp.zeros((j,), jnp.float32)
-        f_nbr = jnp.zeros((j, j), jnp.float32)
         payloads, scale_rows, e_rows = [], [], []
         topo = state.topo
         # scheduler zero-kick (engine side): consume the pending kick
@@ -703,14 +714,21 @@ class ConsensusTrainer:
         # their neighbors' parameters are on THIS round's wire
         kick_on = dynamic and self.topo_cfg.can_gate
         kick_rows = []
+        with span("consensus/fused_round"):      # the kernel's operands
+            ones = jnp.ones((j, self.dequant_spec.scale_width), jnp.float32)
+            sym_sum = jnp.zeros((j,), jnp.float32)
+            if dynamic:
+                mask_f = topo.mask.astype(jnp.float32)
+                act = jnp.zeros((j,), jnp.float32)
         if dynamic:
-            mask_f = topo.mask.astype(jnp.float32)
-            act = jnp.zeros((j,), jnp.float32)
             w_rows = []
             payload_dtype = self.codec.payload_dtype
         # per-node wire accounting for the node ring: offsets whose permute
         # ran AND whose payload this node consumed (mask or pending kick)
-        rx = jnp.zeros((j,), jnp.float32) if self.node_ring_on else None
+        rx = None
+        if self.node_ring_on:
+            with span("obs/ring"):
+                rx = jnp.zeros((j,), jnp.float32)
 
         # ---- pipelined issue phase (pipeline_offsets >= 2) ---------------
         # Reuse the async executor's WireLedger as the sync path's double
@@ -733,10 +751,11 @@ class ConsensusTrainer:
             needs: list = [None] * deg
             if skip_dead:
                 for d0, off0 in enumerate(offsets):
-                    jidx0 = (idx + off0) % j
-                    m0 = mask_f[idx, jidx0]
-                    needs[d0] = m0.sum() if not kick_on \
-                        else m0.sum() + topo.kick[idx, jidx0].sum()
+                    with span("consensus/exchange"):
+                        jidx0 = (idx + off0) % j
+                        m0 = mask_f[idx, jidx0]
+                        needs[d0] = m0.sum() if not kick_on \
+                            else m0.sum() + topo.kick[idx, jidx0].sum()
 
             def _issue_row(d, token=None):
                 src = wire
@@ -746,7 +765,7 @@ class ConsensusTrainer:
                 def _roll(src=src, off_d=offsets[d]):
                     # same barrier discipline as the sequential _exchange:
                     # pins the wire dtype; the span brackets the real wire
-                    with self._span(f"consensus/exchange/off{off_d}"):
+                    with span(f"consensus/exchange/off{off_d}"):
                         return jax.lax.optimization_barrier(
                             jnp.roll(src, -off_d, axis=0))
 
@@ -762,12 +781,14 @@ class ConsensusTrainer:
                 inflight[d0] = _issue_row(d0)
 
         for d, off in enumerate(offsets):
-            jidx = (idx + off) % j
+            with span("consensus/fused_round"):
+                jidx = (idx + off) % j
 
             def _exchange(d=d, off=off):
                 if pipelined:
                     # consume the pre-issued row from the double buffer
-                    payload, scales = self._decode_wire(inflight[d])
+                    with span(f"consensus/exchange/off{off}"):
+                        payload, scales = self._decode_wire(inflight[d])
                 else:
                     # rolled[i] = wire_{(i+off) % j}: ONE collective-
                     # permute on pod moving the whole contiguous buffer
@@ -775,18 +796,19 @@ class ConsensusTrainer:
                     # exchange to the wire dtype — without it XLA hoists
                     # the consumers' f32 upcast above the permute and a
                     # bf16 wire would cross the DCN at 4 B/param.
-                    with self._span(f"consensus/exchange/off{off}"):
+                    with span(f"consensus/exchange/off{off}"):
                         rolled = jax.lax.optimization_barrier(
                             jnp.roll(wire, -off, axis=0))
                         payload, scales = self._decode_wire(rolled)
-                with self._span("consensus/probe"):
+                with span("consensus/probe"):
                     f_off = vloss(self._probe_params(payload, scales),
                                   probe_batch)
                 return payload, (ones if scales is None else scales), f_off
 
             if dynamic:
-                m_off = mask_f[idx, jidx]                          # [J]
-                k_off = topo.kick[idx, jidx] if kick_on else None
+                with span("consensus/fused_round"):
+                    m_off = mask_f[idx, jidx]                      # [J]
+                    k_off = topo.kick[idx, jidx] if kick_on else None
                 if self.topo_cfg.skip_dead_offsets:
                     # an all-gated offset round skips its permute AND its
                     # probe at runtime; the mask is replicated so every
@@ -798,9 +820,12 @@ class ConsensusTrainer:
                         return (jnp.zeros((j, lay.total), payload_dtype),
                                 ones, f_self)
 
-                    need = needs[d] if pipelined \
-                        else (m_off.sum() if not kick_on
-                              else m_off.sum() + k_off.sum())
+                    if pipelined:
+                        need = needs[d]
+                    else:
+                        with span("consensus/exchange"):
+                            need = m_off.sum() if not kick_on \
+                                else m_off.sum() + k_off.sum()
                     payload, scales_row, f_off = jax.lax.cond(
                         need > 0, _exchange, _dead)
                     executed = (need > 0).astype(jnp.float32)
@@ -808,25 +833,33 @@ class ConsensusTrainer:
                     payload, scales_row, f_off = _exchange()
                     executed = jnp.ones((), jnp.float32)
                 if self.node_ring_on:
-                    consumed = m_off + k_off if kick_on else m_off
-                    rx = rx + executed * (consumed > 0).astype(jnp.float32)
+                    with span("obs/ring"):
+                        consumed = m_off + k_off if kick_on else m_off
+                        rx = rx + executed * (consumed > 0).astype(
+                            jnp.float32)
                 if kick_on:
                     kick_rows.append(k_off)
                 # the traced gate flows into the edge weights: a masked
                 # edge costs zero math in the fused kernel
-                e_sym = 0.5 * (eta[idx, jidx] + eta[jidx, idx]) * m_off
-                act = act + m_off
+                with span("consensus/fused_round"):
+                    e_sym = 0.5 * (eta[idx, jidx] + eta[jidx, idx]) * m_off
+                    act = act + m_off
                 w_rows.append(m_off)
             else:
                 payload, scales_row, f_off = _exchange()
                 if self.node_ring_on:
-                    rx = rx + 1.0
-                e_sym = 0.5 * (eta[idx, jidx] + eta[jidx, idx])    # [J]
+                    with span("obs/ring"):
+                        rx = rx + 1.0
+                with span("consensus/fused_round"):
+                    e_sym = 0.5 * (eta[idx, jidx] + eta[jidx, idx])  # [J]
             # scatter-free write of F[i, (i+off)%j]: static circulant mask
             # (an .at[].set scatter costs extra collective-permutes on SPMD)
-            mask = jnp.asarray(np.roll(np.eye(j), off, axis=1), jnp.float32)
-            f_nbr = f_nbr + f_off[:, None] * mask
-            sym_sum = sym_sum + e_sym
+            with span("consensus/probe"):
+                mask = jnp.asarray(np.roll(np.eye(j), off, axis=1),
+                                   jnp.float32)
+                f_nbr = f_nbr + f_off[:, None] * mask
+            with span("consensus/fused_round"):
+                sym_sum = sym_sum + e_sym
             payloads.append(payload)
             scale_rows.append(scales_row)
             e_rows.append(e_sym)
@@ -835,61 +868,64 @@ class ConsensusTrainer:
                 # offset's consume token
                 inflight[d + depth] = _issue_row(d + depth, token=f_off)
 
-        wires = self._constrain_flat(jnp.stack(payloads))  # [deg, J, total]
-        scales = jnp.stack(scale_rows)              # [deg, J, L]
-        e_stack = jnp.stack(e_rows)                 # [deg, J]
-
         # -- fused round: dequant + means + prox + dual + residuals --------
-        alpha = self.ccfg.prox_step / (1.0 + 2.0 * sym_sum)    # [J]
-        if dynamic:
-            # active-degree neighbor mean; ghosts (degree 0) get bar = 0
-            inv_deg = jnp.where(act > 0, 1.0 / jnp.maximum(act, 1.0), 0.0)
-            eta_node = sym_sum * inv_deg
-            bar_w = jnp.stack(w_rows)               # [deg, J]
-        else:
-            eta_node = sym_sum / deg
-            bar_w = inv_deg = None
-        kick_w = jnp.stack(kick_rows) if kick_on else None
-        if self.ccfg.use_fused_kernel:
-            theta_new, lam_new, bar_new, r_sq, s_sq = self._fused_round(
-                theta_flat, state.lam, state.theta_bar_prev, wires, scales,
-                e_stack, alpha, sym_sum, eta_node,
-                bar_w=bar_w, inv_deg=inv_deg, kick_w=kick_w)
-        else:
-            theta_new, lam_new, bar_new, r_sq, s_sq = \
-                kref.consensus_round_ref(
+        with span("consensus/fused_round"):
+            wires = self._constrain_flat(jnp.stack(payloads))  # [deg,J,tot]
+            scales = jnp.stack(scale_rows)              # [deg, J, L]
+            e_stack = jnp.stack(e_rows)                 # [deg, J]
+            alpha = self.ccfg.prox_step / (1.0 + 2.0 * sym_sum)    # [J]
+            if dynamic:
+                # active-degree neighbor mean; ghosts (degree 0) get bar 0
+                inv_deg = jnp.where(act > 0, 1.0 / jnp.maximum(act, 1.0),
+                                    0.0)
+                eta_node = sym_sum * inv_deg
+                bar_w = jnp.stack(w_rows)               # [deg, J]
+            else:
+                eta_node = sym_sum / deg
+                bar_w = inv_deg = None
+            kick_w = jnp.stack(kick_rows) if kick_on else None
+            if self.ccfg.use_fused_kernel:
+                theta_new, lam_new, bar_new, r_sq, s_sq = self._fused_round(
                     theta_flat, state.lam, state.theta_bar_prev, wires,
                     scales, e_stack, alpha, sym_sum, eta_node,
-                    block_leaf=lay.block_leaf, block_size=lay.block_size,
-                    bar_w=bar_w, inv_deg=inv_deg, kick_w=kick_w,
-                    scales_per_block=self.dequant_spec.per_block)
+                    bar_w=bar_w, inv_deg=inv_deg, kick_w=kick_w)
+            else:
+                theta_new, lam_new, bar_new, r_sq, s_sq = \
+                    kref.consensus_round_ref(
+                        theta_flat, state.lam, state.theta_bar_prev, wires,
+                        scales, e_stack, alpha, sym_sum, eta_node,
+                        block_leaf=lay.block_leaf,
+                        block_size=lay.block_size,
+                        bar_w=bar_w, inv_deg=inv_deg, kick_w=kick_w,
+                        scales_per_block=self.dequant_spec.per_block)
+            r_norm = jnp.sqrt(r_sq)
+            s_norm = jnp.sqrt(s_sq)
+        with span("consensus/pack"):
+            params_new = lay.unpack(theta_new)
 
-        params_new = lay.unpack(theta_new)
-        r_norm = jnp.sqrt(r_sq)
-        s_norm = jnp.sqrt(s_sq)
-
-        if dynamic:
-            # penalties keep adapting on gated GRAPH edges (the eq. 10
-            # top-up must still see them to revive) and on repair edges,
-            # but never on ghost rows/cols
-            alive = topo.node_alive
-            adj_pen = (adj & alive[:, None] & alive[None, :]) | topo.mask
-        else:
-            adj_pen = adj
-        with self._span("consensus/penalty"):
+        with span("consensus/penalty"):
+            if dynamic:
+                # penalties keep adapting on gated GRAPH edges (the eq. 10
+                # top-up must still see them to revive) and on repair
+                # edges, but never on ghost rows/cols
+                alive = topo.node_alive
+                adj_pen = (adj & alive[:, None] & alive[None, :]) \
+                    | topo.mask
+            else:
+                adj_pen = adj
             penalty_new = update_penalty(
                 pcfg, state.penalty, adj=adj_pen, f_self=f_self,
                 f_nbr=f_nbr, r_norm=r_norm, s_norm=s_norm)
             topo_new = self.topo_rt.update(
                 topo, penalty=penalty_new,
                 r_norm=r_norm) if dynamic else topo
-        if kick_on:
-            # edges the scheduler just gated: park their final consensus
-            # force (the symmetrized weight applied THIS round) for the
-            # kernel to absorb into the dual next round
-            newly_off = (topo.mask & ~topo_new.mask).astype(jnp.float32)
-            topo_new = topo_new._replace(
-                kick=0.5 * (eta + eta.T) * newly_off)
+            if kick_on:
+                # edges the scheduler just gated: park their final
+                # consensus force (the symmetrized weight applied THIS
+                # round) for the kernel to absorb into the dual next round
+                newly_off = (topo.mask & ~topo_new.mask).astype(jnp.float32)
+                topo_new = topo_new._replace(
+                    kick=0.5 * (eta + eta.T) * newly_off)
         new = state._replace(params=params_new, lam=lam_new,
                              theta_bar_prev=bar_new, penalty=penalty_new,
                              topo=topo_new)
@@ -902,39 +938,46 @@ class ConsensusTrainer:
             # zero-initialized ledger is never decoded), and the dead-offset
             # hold only needs a shape-stable row — so skipping saves a
             # wire-sized [deg, J, W] copy per round.
-            new = new._replace(ledger=WireLedger(
-                wires=self._constrain_flat(jnp.stack(inflight)),
-                round=state.ledger.round + 1,
-                w_prev=0.5 * (eta + eta.T) * (mask_f if dynamic else 1.0)))
-        if dynamic:
-            # ghost and zero-active-degree rows have bar = 0, so their
-            # "residual" is the full parameter norm; an isolated node has
-            # no consensus constraint — exclude both from the extremes
-            alive_f = topo.node_alive.astype(jnp.float32) \
-                * (act > 0).astype(jnp.float32)
-            r_rep, s_rep = r_norm * alive_f, s_norm * alive_f
-            f_rep = (f_self * alive_f).sum() / jnp.maximum(alive_f.sum(), 1)
-        else:
-            r_rep, s_rep, f_rep = r_norm, s_norm, f_self.mean()
-        metrics = {
-            "r_max": r_rep.max(), "s_max": s_rep.max(),
-            "f_mean": f_rep,
-            "eta_mean": jnp.where(adj, penalty_new.eta, 0.0).sum()
-            / jnp.maximum(adj.sum(), 1),
-            "active_edges": (active_edge_fraction(topo, adj) if dynamic
-                             else jnp.ones(())),
-        }
+            with span("consensus/exchange"):
+                new = new._replace(ledger=WireLedger(
+                    wires=self._constrain_flat(jnp.stack(inflight)),
+                    round=state.ledger.round + 1,
+                    w_prev=0.5 * (eta + eta.T)
+                    * (mask_f if dynamic else 1.0)))
+        with span("consensus/penalty"):                # the round's metrics
+            if dynamic:
+                # ghost and zero-active-degree rows have bar = 0, so their
+                # "residual" is the full parameter norm; an isolated node
+                # has no consensus constraint — exclude both from the
+                # extremes
+                alive_f = topo.node_alive.astype(jnp.float32) \
+                    * (act > 0).astype(jnp.float32)
+                r_rep, s_rep = r_norm * alive_f, s_norm * alive_f
+                f_rep = (f_self * alive_f).sum() \
+                    / jnp.maximum(alive_f.sum(), 1)
+            else:
+                r_rep, s_rep, f_rep = r_norm, s_norm, f_self.mean()
+            metrics = {
+                "r_max": r_rep.max(), "s_max": s_rep.max(),
+                "f_mean": f_rep,
+                "eta_mean": jnp.where(adj, penalty_new.eta, 0.0).sum()
+                / jnp.maximum(adj.sum(), 1),
+                "active_edges": (active_edge_fraction(topo, adj) if dynamic
+                                 else jnp.ones(())),
+            }
         node_metrics = None
         if self.node_ring_on:
-            node_metrics = {
-                "r": r_rep, "s": s_rep, "f_local": f_self,
-                "eta_row_mean":
-                    jnp.where(adj, penalty_new.eta, 0.0).sum(axis=1)
-                    / jnp.maximum(adj.sum(axis=1), 1),
-                "alive": (topo.node_alive.astype(jnp.float32) if dynamic
-                          else jnp.ones((j,), jnp.float32)),
-                "wire_rx_bytes": rx * float(self.codec.wire_bytes()),
-            }
+            with span("obs/ring"):
+                node_metrics = {
+                    "r": r_rep, "s": s_rep, "f_local": f_self,
+                    "eta_row_mean":
+                        jnp.where(adj, penalty_new.eta, 0.0).sum(axis=1)
+                        / jnp.maximum(adj.sum(axis=1), 1),
+                    "alive": (topo.node_alive.astype(jnp.float32)
+                              if dynamic
+                              else jnp.ones((j,), jnp.float32)),
+                    "wire_rx_bytes": rx * float(self.codec.wire_bytes()),
+                }
         return self._finish_round(new, metrics, node_metrics)
 
     # ------------------------------------------- async consensus round ----
@@ -1040,9 +1083,9 @@ class ConsensusTrainer:
         newly_stale = prev_base & prev_live & ~live
         kick_m = jnp.where(newly_stale, ledger.w_prev, 0.0) + topo.kick
 
-        with self._span("consensus/probe"):
+        with span("consensus/probe"):
             f_self = vloss(state.params, probe_batch)           # [J]
-        with self._span("consensus/pack"):
+        with span("consensus/pack"):
             theta_flat = self._constrain_flat(
                 lay.pack(state.params, dtype=lay.wire_dtype))
             wire = self._encode_wire(theta_flat)
@@ -1074,7 +1117,7 @@ class ConsensusTrainer:
                 # round k's permute issues regardless of who consumes it
                 # fresh — the overlap the executor's clock accounts for.
                 # The barrier pins the wire dtype (see consensus_step).
-                with self._span(f"consensus/exchange/off{off_d}"):
+                with span(f"consensus/exchange/off{off_d}"):
                     return jax.lax.optimization_barrier(
                         jnp.roll(src, -off_d, axis=0))
 
@@ -1097,7 +1140,7 @@ class ConsensusTrainer:
             k_off = kick_m[idx, jidx]
 
             def _probe(payload=payload, scales_row=scales_row):
-                with self._span("consensus/probe"):
+                with span("consensus/probe"):
                     return vloss(self._probe_params(payload, scales_row),
                                  probe_batch)
 
@@ -1132,10 +1175,11 @@ class ConsensusTrainer:
         inv_deg = jnp.where(act > 0, 1.0 / jnp.maximum(act, 1.0), 0.0)
         eta_node = sym_sum * inv_deg
         if self.ccfg.use_fused_kernel:
-            theta_new, lam_new, bar_new, r_sq, s_sq = self._fused_round(
-                theta_flat, state.lam, state.theta_bar_prev, wires, scales,
-                e_stack, alpha, sym_sum, eta_node,
-                bar_w=bar_w, inv_deg=inv_deg, kick_w=kick_w)
+            with span("consensus/fused_round"):
+                theta_new, lam_new, bar_new, r_sq, s_sq = self._fused_round(
+                    theta_flat, state.lam, state.theta_bar_prev, wires,
+                    scales, e_stack, alpha, sym_sum, eta_node,
+                    bar_w=bar_w, inv_deg=inv_deg, kick_w=kick_w)
         else:
             theta_new, lam_new, bar_new, r_sq, s_sq = \
                 kref.consensus_round_ref(
@@ -1153,7 +1197,7 @@ class ConsensusTrainer:
         # edges (the eq. 10 top-up revives them) but never on ghost rows
         alive = topo.node_alive
         adj_pen = (adj & alive[:, None] & alive[None, :]) | topo.mask
-        with self._span("consensus/penalty"):
+        with span("consensus/penalty"):
             penalty_new = update_penalty(
                 pcfg, state.penalty, adj=adj_pen, f_self=f_self,
                 f_nbr=f_nbr, r_norm=r_norm, s_norm=s_norm)

@@ -833,8 +833,8 @@ def engine_results():
 
 def test_obs_off_is_byte_identical_hlo(engine_results):
     """Acceptance pin: with obs unset (or enabled=False) the compiled
-    consensus step is BYTE-IDENTICAL to a build that never heard of obs —
-    no ring in the state, no spans in the HLO metadata, nothing."""
+    consensus step is BYTE-IDENTICAL either way — no ring in the state or
+    the program (the obs.trace scopes are always compiled in)."""
     assert engine_results["hlo_off_byte_identical"] is True
     assert engine_results["ring_is_none_none"] is True
     assert engine_results["ring_is_none_disabled"] is True

@@ -5,7 +5,9 @@ fused consensus round at the smoke run's real width (qwen3-4b at published
 widths, cut to 4 layers and an 18,944-row vocabulary: 452,526,080 elements
 per node row), in the three variants the trainer selects. Nothing runs;
 the TPU compiler refuses here what it would refuse on the chip (tiling,
-SMEM, VMEM), and the compiled text must hold the Mosaic kernel.
+SMEM, VMEM), and the compiled text must hold the Mosaic kernel. The
+reduced ring's local step and round, compiled for the four chips of a
+v5e:2x2, keep every op of the program under one named phase.
 
 Only one process at a time may load the TPU library, so the topology is
 described inside a module fixture (never at import) and every compile
@@ -28,11 +30,11 @@ J = 1            # one node row per device, as under the trainer's shard_map
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -41,8 +43,13 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture(scope="module")
@@ -98,3 +105,63 @@ def test_fused_round_compiles_per_block_fp8_wire(one_chip, layout):
     # kernel upcasts to f32 before any arithmetic)
     text = _compile(one_chip, layout, jnp.float8_e4m3fn, per_block=True)
     assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def ring_programs(topo):
+    """The reduced qwen3-4b ring's compiled train_step and consensus_step
+    for four described chips, one node each."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_reduced_config
+    from repro.core.penalty import PenaltyConfig
+    from repro.data import DataConfig, SyntheticTokens
+    from repro.kernels import ops
+    from repro.optim import ConsensusConfig, ConsensusTrainer
+    from repro.optim.adamw import AdamWConfig
+
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1, 1),
+                ("pod", "data", "model"))
+    cfg = get_reduced_config("qwen3-4b")
+    tr = ConsensusTrainer(
+        build_model(cfg), mesh, adamw=AdamWConfig(lr=1e-2),
+        consensus=ConsensusConfig(
+            penalty=PenaltyConfig(scheme="nap", eta0=0.1),
+            topology="ring", local_steps=2))
+    state = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        tr.abstract_state(), tr.state_shardings())
+    data = SyntheticTokens(DataConfig(vocab=cfg.vocab, seq_len=32,
+                                      batch_per_node=2, num_nodes=4))
+    pod = NamedSharding(mesh, P("pod"))
+
+    def shapes(batch):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=pod),
+            batch)
+    was = ops.interpret_mode
+    ops.interpret_mode = lambda: False       # the backend here is the CPU
+    try:
+        return {
+            "train_step": jax.jit(tr.train_step).lower(
+                state, shapes(data.batch(0))).compile().as_text(),
+            "consensus_step": jax.jit(tr.consensus_step).lower(
+                state, shapes(data.batch(0, probe=True))).compile().as_text()}
+    finally:
+        ops.interpret_mode = was
+
+
+@pytest.mark.parametrize("program,phases", [
+    ("train_step", {"local/grad", "local/optimizer"}),
+    ("consensus_step", {"consensus/pack", "consensus/exchange",
+                        "consensus/probe", "consensus/fused_round",
+                        "consensus/penalty"})])
+def test_chip_program_ops_lie_under_one_phase(ring_programs, program,
+                                              phases):
+    from hlo_scopes import audit
+    got = audit(ring_programs[program])
+    assert got["bad"] == [], got["bad"][:10]
+    assert set(got["phases"]) == phases
+    if program == "consensus_step":
+        assert "tpu_custom_call" in ring_programs[program]
